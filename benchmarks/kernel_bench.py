@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from .common import emit
 
 # Representative per-layer gradient shapes from the production configs
@@ -220,7 +221,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
     # slower than the per-leaf reference it replaced.
     import functools
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import Compressor
     from repro.core.dcsgd import worker_compress_aggregate
 
@@ -237,7 +238,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
     tname = f"{n_leaves + 3}leaves"
 
     def _make_step(transport, ctx=None):
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         pspec = jax.tree.map(lambda _: P(), tree)
         n_out = 6 if ctx is not None else 5
         return jax.jit(shard_map(
@@ -246,7 +247,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
                               transport_ctx=ctx),
             mesh=mesh, in_specs=(pspec, pspec, P()),
             out_specs=(pspec, pspec) + (P(),) * (n_out - 2),
-            axis_names={"data"}))
+            axis_names={"data"}, check_vma=False))
 
     f_bucketed = _make_step("bucketed")
     f_perleaf = _make_step("perleaf")
@@ -329,7 +330,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
     flat = jax.tree.leaves(tree)
     st = init_overlap_state([x.shape for x in flat],
                             [x.ndim >= 2 for x in flat], comp)
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
     pspec1 = jax.tree.map(lambda _: P(), tree)
     st_spec = jax.tree.map(lambda _: P(), st)
 
@@ -340,7 +341,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
                 transport_ctx=OverlapCtx(cfg=ov_cfg, state=s)),
             mesh=mesh1, in_specs=(pspec1, pspec1, P(), st_spec),
             out_specs=(pspec1, pspec1) + (P(),) * 3 + (st_spec,),
-            axis_names={"data"}))
+            axis_names={"data"}, check_vma=False))
 
     f_stale = _make_overlap(OverlapConfig(n_chunks=2, delay=1))
     us = timeit(f_stale, tree, mem, eta, st, n=n_heavy)
@@ -380,7 +381,7 @@ def main(smoke: bool = False, out_path: str | None = None) -> dict:
         mesh=mesh1, in_specs=(pspec1, pspec1, P(), dl_spec),
         out_specs=(pspec1, pspec1) + (P(),) * 3
         + (DownlinkResult(dl_spec, P(), P()),),
-        axis_names={"data"}))
+        axis_names={"data"}, check_vma=False))
     us = timeit(f_downlink, tree, mem, eta, dls, n=n_heavy)
     record("downlink_step", "compressed", tname, us,
            f"worker_compress_aggregate + server recompression, "

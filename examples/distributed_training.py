@@ -127,7 +127,7 @@ if "XLA_FLAGS" not in os.environ:
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm.faults import FaultConfig
@@ -145,12 +145,13 @@ from repro.launch.train_step import (build_train_step, init_opt_state,
                                      opt_state_shardings)
 from repro.models import build_model
 from repro.sharding import param_shardings
+from repro.launch.mesh import make_mesh
 
 
 def run(kind: str, steps=15, gamma=0.02, transport="bucketed",
         gossip=GossipConfig(), overlap=OverlapConfig(),
         downlink="dense", downlink_gamma=0.0, faults=FaultConfig()):
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("yi-34b")
     model = build_model(cfg)
     run_cfg = RunConfig(
@@ -209,7 +210,7 @@ def run_federated(n_clients: int, clients_per_round: int,
                   aggregation: str, steps=15, gamma=0.05):
     """Non-IID cohort (DESIGN.md §13): W=4 dp workers vmap n_clients/4
     simulated clients each; one all_gather + one psum per round."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("yi-34b")
     model = build_model(cfg)
     run_cfg = RunConfig(

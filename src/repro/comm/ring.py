@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 
 __all__ = [
     "chunk_table",
@@ -104,7 +103,7 @@ def _ring_axis_gather(vec: jax.Array, axis: str, n_chunks: int) -> jax.Array:
     Returns ``(A, len(vec))`` with row ``a`` holding axis-index ``a``'s
     vector — identical to ``lax.all_gather(vec, axis)``.
     """
-    size = int(compat.axis_size(axis))
+    size = jax.lax.axis_size(axis)
     if size == 1:
         return vec[None]
     i = jax.lax.axis_index(axis)

@@ -70,7 +70,6 @@ class ModelConfig:
     compute_dtype: str = "float32"
     attn_chunk: int = 1024        # query-chunked attention above this seq len
     remat: bool = True
-    use_pallas: bool = False      # flip on real TPU
     citation: str = ""
 
     @property

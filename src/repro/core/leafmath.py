@@ -24,7 +24,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.kernels import ops
 from .compression import Compressor, block_extract_sparse
 
@@ -32,7 +31,7 @@ AxisNames = Sequence[str] | str
 
 
 def dp_size(dp_axes: AxisNames):
-    return compat.axis_size(dp_axes)
+    return jax.lax.axis_size(dp_axes)
 
 
 def dp_index(dp_axes: AxisNames):

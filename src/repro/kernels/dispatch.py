@@ -21,6 +21,20 @@ policy, resolved against the active backend:
   model-side ops (attention, rmsnorm, wkv) where the jnp oracle is what the
   CPU dry-run is expected to lower, and for the wire pack/unpack codec.
 
+A compiled TPU kernel is a Mosaic custom call, which XLA cannot
+partition: it lowers only where every mesh axis is manual.  So where some
+axes are still auto at the call site (the train step is manual over dp and
+auto over 'model'), :func:`call` runs a ``pallas-tpu`` implementation in a
+shard_map manual over *every* axis (a nested shard_map that names only the
+auto axes does not count the outer manual ones) with replicated specs:
+along an already-manual axis each worker keeps its own operands, along an
+auto axis every shard computes the whole op.
+
+There is no silent fallback: an op whose resolved implementation is not
+registered raises.  ``with recording() as seen:`` collects, per op, every
+implementation a call resolved to inside the block (at trace time) — the
+record a chip run prints to show that no op on its path ran the oracle.
+
 The bucketed transport (DESIGN.md §11) reuses the registered ``wire_pack``
 / ``wire_unpack`` and EF ops at bucket-shaped geometries — whole-pytree
 field streams and concatenated block rows instead of per-leaf calls — so
@@ -28,8 +42,7 @@ one registry entry serves both the per-leaf reference schedule and the
 coalesced launches; no bucket-specific kernels exist to drift.
 
 ``impl="pallas"`` resolves to the backend-appropriate kernel variant, so
-callers (configs' ``use_pallas``) never hard-code interpret mode.  This
-replaces the scattered module-level ``_INTERPRET`` flags (DESIGN.md §7).
+callers never hard-code interpret mode.
 """
 from __future__ import annotations
 
@@ -37,12 +50,14 @@ import contextlib
 from typing import Callable
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 IMPLS = ("ref", "pallas-interpret", "pallas-tpu")
 
 _REGISTRY: dict[str, dict[str, Callable]] = {}
 _POLICY: dict[str, str] = {}
 _OVERRIDE: str | None = None
+_RECORDERS: list[dict[str, set[str]]] = []
 
 
 def register_op(name: str, *, ref: Callable,
@@ -65,10 +80,7 @@ def registered() -> dict[str, tuple[str, ...]]:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve(name: str, impl: str | None = None) -> str:
@@ -84,10 +96,48 @@ def resolve(name: str, impl: str | None = None) -> str:
 
 
 def call(name: str, *args, impl: str | None = None, **kwargs):
-    """Dispatch ``name`` to the resolved implementation (ref fallback)."""
-    table = _REGISTRY[name]
-    fn = table.get(resolve(name, impl)) or table["ref"]
+    """Dispatch ``name`` to the resolved implementation."""
+    impl = resolve(name, impl)
+    fn = _REGISTRY[name][impl]
+    if fn is None:
+        raise NotImplementedError(f"op {name!r} has no {impl!r} "
+                                  f"implementation (have "
+                                  f"{registered()[name]})")
+    for seen in _RECORDERS:
+        seen.setdefault(name, set()).add(impl)
+    if impl == "pallas-tpu":
+        return _manual_over_auto_axes(fn, args, kwargs)
     return fn(*args, **kwargs)
+
+
+def _manual_over_auto_axes(fn: Callable, args: tuple, kwargs: dict):
+    mesh = jax.sharding.get_abstract_mesh()
+    if set(mesh.axis_names) <= set(mesh.manual_axes):
+        return fn(*args, **kwargs)
+    # array operands enter the shard_map; static ones (ints, None) close over
+    slots = [i for i, a in enumerate(args) if isinstance(a, jax.Array)]
+
+    def body(*arrays):
+        full = list(args)
+        for i, a in zip(slots, arrays):
+            full[i] = a
+        return fn(*full, **kwargs)
+
+    return jax.shard_map(body, in_specs=P(), out_specs=P(),
+                         axis_names=set(mesh.axis_names),
+                         check_vma=False)(*(args[i] for i in slots))
+
+
+@contextlib.contextmanager
+def recording():
+    """``with recording() as seen:`` — ``seen`` maps each op called in the
+    block to the set of implementations its calls resolved to."""
+    seen: dict[str, set[str]] = {}
+    _RECORDERS.append(seen)
+    try:
+        yield seen
+    finally:
+        _RECORDERS.remove(seen)
 
 
 def set_default(impl: str | None) -> None:

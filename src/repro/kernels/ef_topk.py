@@ -53,10 +53,15 @@ def _tile_rows(R: int, interpret: bool) -> int:
     A naive cap wastes up to cap-1 padded rows — on the bucketed
     transport's concatenated block rows (DESIGN.md §11) that was measured
     as ~60% dead work for row counts just past a tile boundary.  Every op
-    here is row-local, so the tiling is numerically invisible."""
+    here is row-local, so the tiling is numerically invisible.
+
+    A tile shorter than R is rounded up to a multiple of 8 sublanes — the
+    TPU compiler refuses any other block height below the full dimension
+    (R=300 would otherwise split into two 150-row tiles)."""
     cap = INTERPRET_ROWS if interpret else ROWS
     n_tiles = -(-R // cap)
-    return -(-R // n_tiles)
+    rows = -(-R // n_tiles)
+    return rows if rows == R else -(-rows // 8) * 8
 
 
 def _kth_largest(mag: jax.Array, k_b: int) -> jax.Array:

@@ -33,8 +33,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
                   window: int | None, bq: int, bk: int, sk: int,
                   q_offset: int):
     qi = pl.program_id(1)
-    # NB: refs must be indexed with slices (pl.dslice / [...]), never bare
-    # Python ints — interpret-mode discharge chokes on raw int indices.
+    # NB: refs are indexed with slices (pl.ds / [...]), never bare Python
+    # ints — interpret-mode discharge chokes on raw int indices.
     q = q_ref[...][0].astype(jnp.float32) * scale       # (BQ, D)
     D = q.shape[-1]
 
@@ -51,10 +51,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(kj, carry):
         acc, m_i, l_i = carry
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kj * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)  # (BK, D)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kj * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        kv_rows = (pl.ds(0, 1), pl.ds(kj * bk, bk), slice(None))
+        k = k_ref[kv_rows][0].astype(jnp.float32)       # (BK, D)
+        v = v_ref[kv_rows][0].astype(jnp.float32)
         s = q @ k.T                                      # (BQ, BK) on MXU
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -91,12 +90,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     bq = min(bq, Sq)
     bk = min(bk, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
+    # ragged lengths (a shifted-by-one training sequence) pad up to whole
+    # tiles: padded keys fail the in-kernel ``kpos < sk`` mask, padded
+    # query rows are sliced off
+    sq_p = -(-Sq // bq) * bq
+    sk_p = -(-Sk // bk) * bk
 
-    qr = q.reshape(B * H, Sq, D)
-    kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
-    grid = (B * H, Sq // bq)
+    def rows(x, s, s_p):
+        x = x.reshape(B * H, s, D)
+        return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0))) if s_p > s else x
+
+    grid = (B * H, sq_p // bq)
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                window=window, bq=bq, bk=bk, sk=Sk,
                                q_offset=Sk - Sq)
@@ -105,11 +109,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, sk_p, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, sk_p, D), lambda b, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, sq_p, D), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(B, H, Sq, D)
+    )(rows(q, Sq, sq_p), rows(k, Sk, sk_p), rows(v, Sk, sk_p))
+    return out[:, :Sq].reshape(B, H, Sq, D)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -89,26 +90,45 @@ dispatch.register_op(
     pallas_tpu=functools.partial(_unpack_words_kernel, interpret=False),
     default="backend")
 
-dispatch.register_op(
-    "attention",
-    ref=ref.mha_reference,
-    pallas_interpret=functools.partial(flash_attention, interpret=True),
-    pallas_tpu=functools.partial(flash_attention, interpret=False),
-    default="backend")
+def _ref_vjp(kernel: Callable, reference: Callable) -> Callable:
+    """``kernel`` forward, ``reference`` backward.
 
-dispatch.register_op(
-    "rmsnorm",
-    ref=ref.rmsnorm_reference,
-    pallas_interpret=functools.partial(rmsnorm, interpret=True),
-    pallas_tpu=functools.partial(rmsnorm, interpret=False),
-    default="backend")
+    The model-side kernels are forward-only ``pallas_call``s, which JAX
+    cannot transpose; the train step differentiates through them.  The
+    VJP recomputes the jnp oracle from the saved inputs and pulls the
+    cotangent back through it.  Keyword arguments are static (flags,
+    window, eps) and bound before the custom_vjp is formed.
+    """
+    def op(*args, **static):
+        fwd_fn = functools.partial(kernel, **static)
+        ref_fn = functools.partial(reference, **static)
 
-dispatch.register_op(
-    "wkv",
-    ref=ref.wkv_reference,
-    pallas_interpret=functools.partial(wkv_forward, interpret=True),
-    pallas_tpu=functools.partial(wkv_forward, interpret=False),
-    default="backend")
+        @jax.custom_vjp
+        def f(*a):
+            return fwd_fn(*a)
+
+        def fwd(*a):
+            return fwd_fn(*a), a
+
+        def bwd(a, ct):
+            return jax.vjp(ref_fn, *a)[1](ct)
+
+        f.defvjp(fwd, bwd)
+        return f(*args)
+    return op
+
+
+for _name, _kernel, _ref in (("attention", flash_attention, ref.mha_reference),
+                             ("rmsnorm", rmsnorm, ref.rmsnorm_reference),
+                             ("wkv", wkv_forward, ref.wkv_reference)):
+    dispatch.register_op(
+        _name,
+        ref=_ref,
+        pallas_interpret=_ref_vjp(
+            functools.partial(_kernel, interpret=True), _ref),
+        pallas_tpu=_ref_vjp(
+            functools.partial(_kernel, interpret=False), _ref),
+        default="backend")
 
 
 # --------------------------------------------------------------------------
@@ -365,12 +385,15 @@ def unpack_fields_stream(words, bits: int, *, impl: str | None = None):
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               scale: float | None = None, q_offset: int | None = None,
               impl: str | None = None):
-    """MHA (B,H,S,D)x(B,H,Sk,D). GQA: broadcast kv heads before calling."""
-    if q_offset is not None or dispatch.resolve("attention", impl) == "ref":
-        return ref.mha_reference(q, k, v, causal=causal, window=window,
-                                 scale=scale, q_offset=q_offset)
-    return dispatch.call("attention", q, k, v, causal=causal, window=window,
-                         scale=scale, impl=impl)
+    """MHA (B,H,S,D)x(B,H,Sk,D). GQA: broadcast kv heads before calling.
+
+    ``q_offset`` (absolute position of the first query, may be traced) is
+    taken by the oracle only; the kernel places queries at the trailing
+    Sq positions."""
+    kw = dict(causal=causal, window=window, scale=scale)
+    if q_offset is not None:
+        kw["q_offset"] = q_offset
+    return dispatch.call("attention", q, k, v, impl=impl, **kw)
 
 
 def rms_norm(x, w, *, eps: float = 1e-6, impl: str | None = None):

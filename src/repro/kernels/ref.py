@@ -205,14 +205,13 @@ def wkv_reference(r, k, v, w, u, s0):
 
     r/k/v/w: (B, S, H, K|V); u: (H, K); s0: (B, H, K, V).
     Returns (y: (B, S, H, V), sT)."""
-    B, S, H, K = r.shape
-    S_state = s0.astype(jnp.float32)
-    ys = []
-    for t in range(S):
-        kv = jnp.einsum("bhk,bhv->bhkv", k[:, t].astype(jnp.float32),
-                        v[:, t].astype(jnp.float32))
-        y = jnp.einsum("bhk,bhkv->bhv", r[:, t].astype(jnp.float32),
+    def step(S_state, inp):
+        rt, kt, vt, wt = inp                        # (B, H, K|V) each
+        kv = jnp.einsum("bhk,bhv->bhkv", kt, vt)
+        y = jnp.einsum("bhk,bhkv->bhv", rt,
                        S_state + u[None, :, :, None] * kv)
-        ys.append(y)
-        S_state = w[:, t].astype(jnp.float32)[..., None] * S_state + kv
-    return jnp.stack(ys, axis=1), S_state
+        return wt[..., None] * S_state + kv, y
+
+    seq = [a.transpose(1, 0, 2, 3).astype(jnp.float32) for a in (r, k, v, w)]
+    sT, ys = jax.lax.scan(step, s0.astype(jnp.float32), tuple(seq))
+    return ys.transpose(1, 0, 2, 3), sT

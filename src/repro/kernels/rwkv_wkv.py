@@ -30,14 +30,13 @@ from jax.experimental import pallas as pl
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
                 *, seq_len: int):
-    # NB: refs are indexed with slices (pl.dslice / [...]), never bare
-    # Python ints — interpret-mode discharge chokes on raw int indices.
+    # NB: refs are indexed with slices (pl.ds / [...]), never bare Python
+    # ints — interpret-mode discharge chokes on raw int indices.
     S = s0_ref[...][0].astype(jnp.float32)         # (K, V)
     u = u_ref[...][0].astype(jnp.float32)          # (K,)
 
     def _step(ref, t):
-        return pl.load(ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                             slice(None)))[0, 0]
+        return ref[pl.ds(0, 1), pl.ds(t, 1), :][0, 0]
 
     def body(t, S):
         rt = _step(r_ref, t).astype(jnp.float32)   # (K,)
@@ -46,8 +45,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
         wt = _step(w_ref, t).astype(jnp.float32)   # (K,)
         kv = kt[:, None] * vt[None, :]             # (K, V) outer
         y = jnp.sum(rt[:, None] * (S + u[:, None] * kv), axis=0)
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y.astype(y_ref.dtype)[None, None])
+        y_ref[pl.ds(0, 1), pl.ds(t, 1), :] = y.astype(y_ref.dtype)[None, None]
         return wt[:, None] * S + kv
 
     S = jax.lax.fori_loop(0, seq_len, body, S)

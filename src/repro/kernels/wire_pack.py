@@ -4,16 +4,19 @@ The packed payload (DESIGN.md §8) stores each wire entry's index and
 quantized value as fixed-width bit-fields inside contiguous ``uint32``
 words.  The field<->word conversion is the only data-parallel part of the
 codec and the part worth a kernel: on TPU it is a pure VPU shift/or (pack)
-or shift/mask (unpack) streaming pass — one read + one write at the packed
-byte width, so packing k int8 values costs k bytes of HBM traffic, not 4k.
+or shift/mask (unpack) pass over lane-dense (rows, chunk) tiles.
 
 Layout contract (shared with the ``kernels/ref.py`` oracles bit-for-bit):
 ``F = 32 // bits`` fields per word, field ``f`` occupying bits
 ``[f*bits, (f+1)*bits)`` — little-endian fields within each word.
 
-Tiles are (rows, chunk) with the word chunk VPU-lane aligned; the field
-side of each tile is ``F`` times wider than the word side, expressed as two
-BlockSpec widths over the same grid.
+The TPU compiler has no lane-strided load or store, and splitting the lane
+dimension into (words, F) inside a kernel is either refused (pack) or
+unrolled into a compile that runs for minutes (unpack).  So the kernels
+work on *field planes* — ``planes[s, r, w] = fields[r, w*F + s]`` — and
+the interleave between fields and planes is one XLA transpose outside the
+kernel: pack ORs the F shifted planes of a tile into its words, unpack
+writes the F masked shifts of its words to the F planes.
 """
 from __future__ import annotations
 
@@ -46,64 +49,65 @@ def stream_shape(n_words: int) -> tuple[int, int]:
     return -(-max(n_words, 1) // cols), cols
 
 
-def _field_mask(c_ref, n: int, rows: int, period: int):
-    """(rows, n) validity mask for the current grid tile: GLOBAL field
-    index j (tile column offset + local column) is valid iff
-    ``j % period < count[row]`` — the ragged-payload predicate.  The
-    modulo makes it a per-block prefix for block-local wire rows and a
-    plain prefix for flat rows, with zero extra HBM traffic: counts ride
-    in as one (rows, 1) int32 column per tile."""
-    j = pl.program_id(1)
-    gidx = j * n + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
-    return (gidx % period) < c_ref[...]
+def _field_mask(c_ref, s: int, rows: int, wc: int, F: int, period: int):
+    """(rows, wc) validity mask of field plane ``s`` in the current grid
+    tile: the field of word column ``w`` (global) and plane ``s`` has row
+    index ``j = w*F + s``, valid iff ``j % period < count[row]`` — the
+    ragged-payload predicate.  The modulo makes it a per-block prefix for
+    block-local wire rows and a plain prefix for flat rows, with zero extra
+    HBM traffic: counts ride in as one (rows, 1) int32 column per tile."""
+    w = pl.program_id(1) * wc + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, wc), 1)
+    return ((w * F + s) % period) < c_ref[...]
 
 
-def _pack_kernel(f_ref, out_ref, *, bits: int):
-    """(rows, W*F) uint32 fields -> (rows, W) uint32 words."""
+def _pack_kernel(f_ref, *refs, bits: int, period: int):
+    """(F, rows, wc) field planes -> (rows, wc) words: an OR-fold of the
+    F shifted planes (disjoint bit ranges).  With ``period`` > 0 the first
+    of ``refs`` is the (rows, 1) count column and fields beyond the
+    per-row valid count are zeroed on the same pass."""
+    out_ref = refs[-1]
     F = 32 // bits
-    f = f_ref[...].astype(jnp.uint32) & jnp.uint32((1 << bits) - 1)
-    rows, n = f.shape
-    shifts = jnp.arange(F, dtype=jnp.uint32) * jnp.uint32(bits)
-    w = f.reshape(rows, n // F, F) << shifts[None, None, :]
-    # disjoint bit ranges: or == sum, and sum lowers to a VPU reduction
-    out_ref[...] = jnp.sum(w, axis=-1, dtype=jnp.uint32)
+    _, rows, wc = f_ref.shape
+    acc = None
+    for s in range(F):
+        f = f_ref[s] & jnp.uint32((1 << bits) - 1)
+        if period:
+            f = jnp.where(_field_mask(refs[0], s, rows, wc, F, period), f,
+                          jnp.uint32(0))
+        f = f << jnp.uint32(s * bits)
+        acc = f if acc is None else acc | f
+    out_ref[...] = acc
 
 
-def _pack_kernel_ragged(f_ref, c_ref, out_ref, *, bits: int, period: int):
-    """Ragged variant: zero fields beyond the per-row valid count on the
-    same streaming pass, then pack."""
+def _unpack_kernel(w_ref, *refs, bits: int, period: int):
+    """(rows, wc) words -> (F, rows, wc) field planes; with ``period`` > 0
+    fields beyond the valid count come out 0 regardless of the packed
+    tail's bytes."""
+    out_ref = refs[-1]
     F = 32 // bits
-    f = f_ref[...].astype(jnp.uint32) & jnp.uint32((1 << bits) - 1)
-    rows, n = f.shape
-    f = jnp.where(_field_mask(c_ref, n, rows, period), f, jnp.uint32(0))
-    shifts = jnp.arange(F, dtype=jnp.uint32) * jnp.uint32(bits)
-    w = f.reshape(rows, n // F, F) << shifts[None, None, :]
-    out_ref[...] = jnp.sum(w, axis=-1, dtype=jnp.uint32)
+    w = w_ref[...]
+    rows, wc = w.shape
+    for s in range(F):
+        f = (w >> jnp.uint32(s * bits)) & jnp.uint32((1 << bits) - 1)
+        if period:
+            f = jnp.where(_field_mask(refs[0], s, rows, wc, F, period), f,
+                          jnp.uint32(0))
+        out_ref[s] = f
 
 
-def _unpack_kernel(w_ref, out_ref, *, bits: int):
-    """(rows, W) uint32 words -> (rows, W*F) uint32 fields."""
-    F = 32 // bits
-    w = w_ref[...].astype(jnp.uint32)
-    rows, W = w.shape
-    mask = jnp.uint32((1 << bits) - 1)
-    shifts = jnp.arange(F, dtype=jnp.uint32) * jnp.uint32(bits)
-    fields = (w[:, :, None] >> shifts[None, None, :]) & mask
-    out_ref[...] = fields.reshape(rows, W * F)
+def _geometry(R: int, W: int):
+    rows = min(ROWS, R)
+    wc = min(WORD_CHUNK, W)
+    return rows, wc, (pl.cdiv(R, rows), pl.cdiv(W, wc))
 
 
-def _unpack_kernel_ragged(w_ref, c_ref, out_ref, *, bits: int, period: int):
-    """Ragged variant: decoded fields beyond the valid count come out 0
-    regardless of the packed tail's bytes."""
-    F = 32 // bits
-    w = w_ref[...].astype(jnp.uint32)
-    rows, W = w.shape
-    mask = jnp.uint32((1 << bits) - 1)
-    shifts = jnp.arange(F, dtype=jnp.uint32) * jnp.uint32(bits)
-    fields = (w[:, :, None] >> shifts[None, None, :]) & mask
-    fields = fields.reshape(rows, W * F)
-    out_ref[...] = jnp.where(_field_mask(c_ref, W * F, rows, period),
-                             fields, jnp.uint32(0))
+def _counts_operand(counts, rows: int):
+    """Extra (operand, BlockSpec) for a ragged launch, else nothing."""
+    if counts is None:
+        return [], []
+    c = jnp.asarray(counts, jnp.int32).reshape(-1, 1)
+    return [c], [pl.BlockSpec((rows, 1), lambda i, j: (i, 0))]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "period", "interpret"))
@@ -127,28 +131,20 @@ def pack_words(fields: jax.Array, bits: int,
     F = 32 // bits
     R, n = fields.shape
     W = n // F
-    rows = min(ROWS, R)
-    wc = min(WORD_CHUNK, W)
-    grid = (pl.cdiv(R, rows), pl.cdiv(W, wc))
-    if counts is None:
-        return pl.pallas_call(
-            functools.partial(_pack_kernel, bits=bits),
-            grid=grid,
-            in_specs=[pl.BlockSpec((rows, wc * F), lambda i, j: (i, j))],
-            out_specs=pl.BlockSpec((rows, wc), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((R, W), jnp.uint32),
-            interpret=interpret,
-        )(fields.astype(jnp.uint32))
-    c = jnp.asarray(counts, jnp.int32).reshape(-1, 1)
+    rows, wc, grid = _geometry(R, W)
+    # field planes: planes[s, r, w] = fields[r, w*F + s]
+    planes = fields.astype(jnp.uint32).reshape(R, W, F).transpose(2, 0, 1)
+    extra, extra_specs = _counts_operand(counts, rows)
     return pl.pallas_call(
-        functools.partial(_pack_kernel_ragged, bits=bits, period=period),
+        functools.partial(_pack_kernel, bits=bits,
+                          period=period if counts is not None else 0),
         grid=grid,
-        in_specs=[pl.BlockSpec((rows, wc * F), lambda i, j: (i, j)),
-                  pl.BlockSpec((rows, 1), lambda i, j: (i, 0))],
+        in_specs=[pl.BlockSpec((F, rows, wc), lambda i, j: (0, i, j))]
+        + extra_specs,
         out_specs=pl.BlockSpec((rows, wc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, W), jnp.uint32),
         interpret=interpret,
-    )(fields.astype(jnp.uint32), c)
+    )(planes, *extra)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "period", "interpret"))
@@ -167,25 +163,16 @@ def unpack_words(words: jax.Array, bits: int,
         return out
     F = 32 // bits
     R, W = words.shape
-    rows = min(ROWS, R)
-    wc = min(WORD_CHUNK, W)
-    grid = (pl.cdiv(R, rows), pl.cdiv(W, wc))
-    if counts is None:
-        return pl.pallas_call(
-            functools.partial(_unpack_kernel, bits=bits),
-            grid=grid,
-            in_specs=[pl.BlockSpec((rows, wc), lambda i, j: (i, j))],
-            out_specs=pl.BlockSpec((rows, wc * F), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((R, W * F), jnp.uint32),
-            interpret=interpret,
-        )(words.astype(jnp.uint32))
-    c = jnp.asarray(counts, jnp.int32).reshape(-1, 1)
-    return pl.pallas_call(
-        functools.partial(_unpack_kernel_ragged, bits=bits, period=period),
+    rows, wc, grid = _geometry(R, W)
+    extra, extra_specs = _counts_operand(counts, rows)
+    planes = pl.pallas_call(
+        functools.partial(_unpack_kernel, bits=bits,
+                          period=period if counts is not None else 0),
         grid=grid,
-        in_specs=[pl.BlockSpec((rows, wc), lambda i, j: (i, j)),
-                  pl.BlockSpec((rows, 1), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((rows, wc * F), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, W * F), jnp.uint32),
+        in_specs=[pl.BlockSpec((rows, wc), lambda i, j: (i, j))]
+        + extra_specs,
+        out_specs=pl.BlockSpec((F, rows, wc), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((F, R, W), jnp.uint32),
         interpret=interpret,
-    )(words.astype(jnp.uint32), c)
+    )(words.astype(jnp.uint32), *extra)
+    return planes.transpose(1, 2, 0).reshape(R, W * F)

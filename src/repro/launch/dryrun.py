@@ -25,7 +25,7 @@ import traceback
 
 import jax
 
-from repro.compat import set_mesh
+from jax import set_mesh
 import jax.numpy as jnp
 
 from repro.comm.faults import FaultConfig
@@ -447,8 +447,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         rec["compile_s"] = round(time.time() - t1, 2)
 
-        from repro.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         # raw XLA numbers (per-device, while-bodies counted ONCE — kept as
         # diagnostics; the trip-count-aware numbers below are authoritative)
         rec["xla_flops_body_once"] = float(ca.get("flops", 0.0))

@@ -11,25 +11,19 @@ mesh with the same sharded cache layout.
 from __future__ import annotations
 
 import argparse
-import math
 import time
 
 import jax
 
-from repro.compat import set_mesh
+from jax import set_mesh
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import parse_mesh
 from repro.models import build_model
 from repro.sharding import dp_axes_of
-
-
-def parse_mesh(spec: str):
-    dims = tuple(int(x) for x in spec.split("x"))
-    axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(dims, axes, devices=jax.devices()[:math.prod(dims)])
 
 
 def main() -> None:

@@ -8,6 +8,11 @@ CPU-scale entry point (the production mesh path is exercised by dryrun.py):
 
 Runs real steps on the (forced-host) mesh, logs loss/alpha/wire-bytes, and
 writes checkpoints.  ``--arch paper-lm-100m`` is the ~100M end-to-end run.
+
+:func:`main` takes an argv list and returns the run's record, so a caller
+that must keep the chip in one process (``chip_smoke.py``) drives it
+in-process.  Compiled programs persist in JAX's compilation cache:
+``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -15,12 +20,14 @@ import argparse
 import json
 import math
 import os
+import pathlib
+import statistics
 import time
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpoint as ckpt
@@ -38,22 +45,26 @@ from repro.core.gamma import GammaControllerConfig
 from repro.core.health import check_divergence
 from repro.data.synthetic import TokenPipeline
 from repro.fed.sampling import participation_mask
+from repro.launch.mesh import parse_mesh
 from repro.launch.train_step import (build_train_step, init_opt_state,
                                      opt_state_shardings)
 from repro.models import build_model
 from repro.sharding import dp_axes_of, param_shardings
 
 
-def parse_mesh(spec: str):
-    dims = tuple(int(x) for x in spec.split("x"))
-    if len(dims) == 2:
-        return jax.make_mesh(dims, ("data", "model"),
-                             devices=jax.devices()[:math.prod(dims)])
-    return jax.make_mesh(dims, ("pod", "data", "model"),
-                         devices=jax.devices()[:math.prod(dims)])
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 
-def main() -> None:
+def init_compile_cache() -> None:
+    """Persistent compilation cache: JAX reads ``JAX_COMPILATION_CACHE_DIR``
+    itself; without it, one fixed git-ignored directory in the checkout
+    (a fixed path, since the path is part of the cache key)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-lm-100m")
     ap.add_argument("--smoke", action="store_true",
@@ -227,15 +238,14 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out", default=None, help="JSON metrics log")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run_config(args: argparse.Namespace) -> RunConfig:
+    """The RunConfig the CLI flags describe."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = build_model(cfg)
-    mesh = parse_mesh(args.mesh)
-    dp = dp_axes_of(mesh)
-    W = math.prod(mesh.shape[a] for a in dp)
     shape = ShapeConfig("cli", args.seq_len, args.global_batch, "train")
-    run = RunConfig(
+    return RunConfig(
         model=cfg, shape=shape,
         optimizer=OptimizerConfig(
             kind=args.opt, armijo=ArmijoConfig(theory_safe=args.theory_safe),
@@ -284,6 +294,20 @@ def main() -> None:
                                quarantine=not args.no_quarantine),
             max_consecutive_skips=args.max_consecutive_skips),
         microbatches=args.microbatches)
+
+
+def main(argv: list[str] | None = None) -> dict:
+    """Train; returns ``{"log", "compile_s", "step_s"}`` — the logged
+    metric rows, the train-step compile seconds and every step's wall
+    seconds (dispatch to ``block_until_ready``)."""
+    init_compile_cache()
+    args = parse_args(argv)
+    run = run_config(args)
+    cfg = run.model
+    model = build_model(cfg)
+    mesh = parse_mesh(args.mesh)
+    dp = dp_axes_of(mesh)
+    W = math.prod(mesh.shape[a] for a in dp)
 
     with set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
@@ -342,15 +366,21 @@ def main() -> None:
 
         step_fn = None
         log = []
+        compile_s = 0.0
+        step_s = []
         t_start = time.time()
         for step in range(start, args.steps):
             batch = put_batch(make_batch(step))
             if step_fn is None:
                 step_fn = build_train_step(model, run, mesh)(params, batch)
-                t0 = time.time()
+                t0 = time.perf_counter()
                 step_fn = step_fn.lower(params, opt_state, batch).compile()
-                print(f"compiled train_step in {time.time()-t0:.1f}s")
+                compile_s = time.perf_counter() - t0
+                print(f"compiled train_step in {compile_s:.1f}s")
+            t0 = time.perf_counter()
             params, opt_state, metrics = step_fn(params, opt_state, batch)
+            jax.block_until_ready(metrics)
+            step_s.append(time.perf_counter() - t0)
             if run.optimizer.max_consecutive_skips > 0:
                 # host-side breaker: DivergenceError is a typed Python
                 # exception, impossible to raise from inside jit
@@ -390,6 +420,10 @@ def main() -> None:
             os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
             with open(args.out, "w") as f:
                 json.dump(log, f, indent=1)
+        if len(step_s) > 1:
+            print(f"median step {statistics.median(step_s[1:]):.4f}s "
+                  f"over {len(step_s) - 1} steps after the first")
+    return {"log": log, "compile_s": compile_s, "step_s": step_s}
 
 
 if __name__ == "__main__":

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm.downlink import (DownlinkCtx, DownlinkState,
                                  init_downlink_state)
 from repro.comm.faults import FaultCtx, active_faults
@@ -187,16 +186,11 @@ def opt_state_shardings(opt_state: DistOptState, params: PyTree, mesh,
     dp = dp_axes_of(mesh)
     dp_spec = dp if len(dp) > 1 else dp[0]
     pspecs = param_pspecs(params)
-    if not compat.PARTIAL_AUTO_SAFE:
-        # 0.4.x: model-sharded state entering the manual-dp shard_map's
-        # scan crashes XLA — keep trailing dims replicated (compat.py).
-        pspecs = jax.tree.map(lambda _: P(), pspecs)
     mem_kind = ("pinned_host" if run_cfg.optimizer.ef_host_offload
                 else None)
 
     def mem_sh(ps):
-        return compat.named_sharding(mesh, P(dp_spec, *ps),
-                                     memory_kind=mem_kind)
+        return NamedSharding(mesh, P(dp_spec, *ps), memory_kind=mem_kind)
 
     rep = NamedSharding(mesh, P())
     vec = NamedSharding(mesh, P(dp_spec))
@@ -211,8 +205,7 @@ def opt_state_shardings(opt_state: DistOptState, params: PyTree, mesh,
         cum_eff_bytes=rep,
         gossip=(GossipOptState(
             params=jax.tree.map(
-                lambda ps: compat.named_sharding(mesh, P(dp_spec, *ps)),
-                pspecs),
+                lambda ps: NamedSharding(mesh, P(dp_spec, *ps)), pspecs),
             state=GossipState(v=vec, lr=vec))
             if opt_state.gossip != () else ()),
         fed=(ClientState(
@@ -224,7 +217,7 @@ def opt_state_shardings(opt_state: DistOptState, params: PyTree, mesh,
         downlink=(jax.tree.map(lambda _: vec, opt_state.downlink)
                   if opt_state.downlink != () else ()),
         velocity=(jax.tree.map(
-            lambda ps: compat.named_sharding(mesh, P(dp_spec, *ps)), pspecs)
+            lambda ps: NamedSharding(mesh, P(dp_spec, *ps)), pspecs)
             if opt_state.velocity != () else ()),
         health=jax.tree.map(lambda _: vec, opt_state.health),
     )
@@ -740,7 +733,7 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                 send = grads
                 new_velocity = opt_state.velocity
             dl_res = None
-            if opt.shard_local_topk and compat.PARTIAL_AUTO_SAFE:
+            if opt.shard_local_topk:
                 # per-(layer, model-shard) top_k: nested manual-'model'
                 # region so selection runs on the local gradient shard and
                 # the only collective stays the small dp packed all-gather.
@@ -749,7 +742,7 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                 # telemetry sums psum over 'model' before the ratios form
                 # (the P() out_spec asserts them replicated; wire/eff are
                 # shape-derived and replicated without it)
-                inner = compat.shard_map(
+                inner = jax.shard_map(
                     lambda g, m2, e, gt: worker_compress_aggregate(
                         g, m2, e, opt.compressor, dp, stacked_mask=smask,
                         gamma_t=gt, telemetry_axes=("model",),
@@ -810,13 +803,6 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                         stacked_mask=smask, gamma_t=gamma_t,
                         transport=t_name, transport_ctx=t_ctx)
             else:
-                # covers shard_local_topk on 0.4.x too: there the training
-                # body is already manual over 'model' (compat.
-                # PARTIAL_AUTO_SAFE) with the model axis replicated, so
-                # grads ARE the per-shard local view — re-nesting a
-                # manual-'model' shard_map around it SIGFPEs 0.4.x XLA
-                # (tests/distributed/test_shard_local_topk.py) and
-                # shard-local selection degenerates to the direct call.
                 updates, new_mem, wire, eff_wire, tel = \
                     worker_compress_aggregate(
                         send, mem, eta, opt.compressor, dp,
@@ -988,24 +974,16 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
              if downlink_mode and not fed_mode else ())
         metrics_spec = {k: rep for k in metric_keys}
         # Manual over dp, auto over 'model' (XLA partitions the TP math).
-        # On 0.4.x partial-auto shard_map cannot contain a lax.scan
-        # (compat.PARTIAL_AUTO_SAFE), so there the body is manual over
-        # EVERY axis and the model axis simply replicates the worker math.
-        manual = set(dp) if compat.PARTIAL_AUTO_SAFE \
-            else set(mesh.axis_names)
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             worker_fn, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, params_like),
                       state_in, batch_spec_of(batch_like)),
             out_specs=(jax.tree.map(lambda _: rep, params_like),
                        state_in, metrics_spec),
-            axis_names=manual, check_vma=False)
-        # outer jit: model-axis shardings (replicated on 0.4.x — see
-        # compat.PARTIAL_AUTO_SAFE)
-        pspecs = param_pspecs(params_like)
-        if not compat.PARTIAL_AUTO_SAFE:
-            pspecs = jax.tree.map(lambda _: P(), pspecs)
-        psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
+            axis_names=set(dp), check_vma=False)
+        # outer jit: model-axis shardings
+        psh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                           param_pspecs(params_like))
         opt_sh = opt_state_shardings(
             init_opt_state(params_like, run_cfg, W, abstract=True,
                            stacked_mask=model.stacked_mask(params_like)),

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.kernels import ops
+from repro.kernels import dispatch, ops
 from repro.utils import DP, TP, hint
 from .layers import apply_rope, dense, he_init
 
@@ -97,14 +97,14 @@ def _sdpa(q, k, v, cfg: ModelConfig, causal: bool, window: int | None):
     """q,k,v: (B, S, H, hd) -> (B, Sq, H, hd); query-chunked if long."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
-    impl = "pallas" if cfg.use_pallas else "ref"
     qT = q.transpose(0, 2, 1, 3)
     kT = k.transpose(0, 2, 1, 3)
     vT = v.transpose(0, 2, 1, 3)
     chunk = cfg.attn_chunk
-    if Sq <= chunk:
-        out = ops.attention(qT, kT, vT, causal=causal, window=window,
-                            impl=impl)
+    # the flash kernel tiles the queries itself; query chunking only bounds
+    # the oracle's (Sq, Sk) logits
+    if Sq <= chunk or dispatch.resolve("attention") != "ref":
+        out = ops.attention(qT, kT, vT, causal=causal, window=window)
     else:
         pad = (-Sq) % chunk
         qp = jnp.pad(qT, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else qT

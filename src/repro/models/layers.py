@@ -37,9 +37,8 @@ def dense(p, x, tp_dim: str | None = None):
     return y
 
 
-def rms_norm(p, x, eps: float, use_pallas: bool = False):
-    return ops.rms_norm(x, p["w"], eps=eps,
-                        impl="pallas" if use_pallas else "ref")
+def rms_norm(p, x, eps: float):
+    return ops.rms_norm(x, p["w"], eps=eps)
 
 
 def init_rms_norm(d, dtype):

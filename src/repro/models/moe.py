@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.utils import DP, TP, hint
 from .layers import he_init
@@ -33,8 +32,8 @@ def _maybe_expert_parallel(p, x, cfg: ModelConfig, no_drop: bool):
     output; a single activation-sized ``psum`` over 'model' combines.
     Returns None when no mesh/model axis is active (CPU smoke path).
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return None
     manual = set(mesh.manual_axes)
     if "model" in manual:
@@ -69,7 +68,7 @@ def _maybe_expert_parallel(p, x, cfg: ModelConfig, no_drop: bool):
             aux = jax.lax.pmean(aux, tuple(dp))
         return jax.lax.psum(y, "model"), aux
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(), wspec, wspec, wspec),
         out_specs=(xspec, P()),

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.utils import DP, TP, hint
 from .layers import he_init
 
@@ -107,31 +108,11 @@ def time_mix(p, x, cfg: ModelConfig, state: RWKVState):
                           @ p["lora_B"]["w"].astype(x.dtype)).astype(jnp.float32)
     wdec = jnp.exp(-jnp.exp(wdec.astype(jnp.float32))).reshape(B, L, H, hd)
 
-    u = p["u"]
-
-    if cfg.use_pallas:
-        # VMEM-resident WKV kernel (kernels/rwkv_wkv.py): eliminates the
-        # per-step HBM state round-trip that makes the scan memory-bound.
-        from repro.kernels import ops as kops
-        y4, S_final = kops.wkv(r.astype(jnp.float32), k.astype(jnp.float32),
-                               v.astype(jnp.float32), wdec, u, state.wkv,
-                               impl="pallas")
-        y = y4.reshape(B, L, D)
-    else:
-        def step(S, inp):
-            rt, kt, vt, wt = inp          # (B,H,hd) each
-            kv = jnp.einsum("bhk,bhv->bhkv", kt, vt)
-            out = jnp.einsum("bhk,bhkv->bhv", rt,
-                             S + u[None, :, :, None] * kv)
-            S = wt[..., None] * S + kv
-            return S, out
-
-        rs = r.transpose(1, 0, 2, 3).astype(jnp.float32)
-        ks_ = k.transpose(1, 0, 2, 3).astype(jnp.float32)
-        vs = v.transpose(1, 0, 2, 3).astype(jnp.float32)
-        ws = wdec.transpose(1, 0, 2, 3)
-        S_final, outs = jax.lax.scan(step, state.wkv, (rs, ks_, vs, ws))
-        y = outs.transpose(1, 0, 2, 3).reshape(B, L, D)
+    # on TPU the VMEM-resident WKV kernel (kernels/rwkv_wkv.py) removes the
+    # per-step HBM state round-trip that makes the scan memory-bound
+    y4, S_final = ops.wkv(r.astype(jnp.float32), k.astype(jnp.float32),
+                          v.astype(jnp.float32), wdec, p["u"], state.wkv)
+    y = y4.reshape(B, L, D)
     y = _group_norm(y, p["ln_w"], p["ln_b"], H, hd).astype(x.dtype) * g
     out = hint(y @ p["wo"]["w"].astype(x.dtype), DP, None, None)
     new_state = state._replace(tm_prev=x[:, -1].astype(jnp.float32),

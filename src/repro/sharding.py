@@ -110,18 +110,9 @@ def param_pspecs(params: PyTree, two_d: bool = False,
 
 
 def param_shardings(params: PyTree, mesh) -> PyTree:
-    """Training-path parameter placement (serving uses param_pspecs).
-
-    On 0.4.x JAX the manual-dp train shard_map cannot carry model-sharded
-    operands through the layer scan (compat.PARTIAL_AUTO_SAFE), so params
-    are kept replicated there; the pspecs themselves are unchanged.
-    """
-    from repro import compat
-    specs = param_pspecs(params)
-    if not compat.PARTIAL_AUTO_SAFE:
-        from jax.sharding import PartitionSpec
-        specs = jax.tree.map(lambda _: PartitionSpec(), specs)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+    """Training-path parameter placement (serving uses param_pspecs)."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        param_pspecs(params))
 
 
 def dp_axes_of(mesh) -> tuple[str, ...]:

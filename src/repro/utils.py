@@ -7,8 +7,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 PyTree = Any
 
 # Symbolic axis names used throughout the model code; resolved against the
@@ -19,9 +17,7 @@ TP = "tp"
 
 
 def _active_axes() -> tuple[tuple[str, ...], str | None]:
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
-        return (), None
+    mesh = jax.sharding.get_abstract_mesh()
     manual = set(mesh.manual_axes)
     names = [a for a in mesh.axis_names if a not in manual]
     dp = tuple(a for a in names if a in ("pod", "data", "replica"))
@@ -36,10 +32,6 @@ def hint(x: jax.Array, *spec: Any) -> jax.Array:
     current (non-manual) mesh are dropped, so the same model code runs on a
     bare CPU, inside a manual-over-data shard_map, or under full-auto pjit.
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is not None and mesh.manual_axes \
-            and not compat.PARTIAL_AUTO_SAFE:
-        return x  # see compat.PARTIAL_AUTO_SAFE
     dp, tp = _active_axes()
     if not dp and tp is None:
         return x
@@ -64,10 +56,7 @@ def hint(x: jax.Array, *spec: Any) -> jax.Array:
     resolved = tuple(resolve(e) for e in spec)
     if all(e is None for e in resolved):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*resolved))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*resolved))
 
 
 def cast_tree(tree: PyTree, dtype) -> PyTree:

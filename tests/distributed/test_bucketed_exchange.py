@@ -18,10 +18,11 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
 from repro.core.telemetry import CompressionTelemetry
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -39,7 +40,7 @@ def _worker_tree(key, n_workers=W_WORKERS):
 
 def _run(gtree, mtree, gammas, comp, transport,
          mesh_shape=(W_WORKERS,), axes=("data",), eta=0.1):
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.downlink import (DownlinkCtx, DownlinkState,
                                  apply_downlink, dense_downlink_bytes,
                                  downlink_plan, downlink_wire_bytes,
@@ -31,6 +31,7 @@ from repro.comm.downlink import (DownlinkCtx, DownlinkState,
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
 from repro.core.telemetry import CompressionTelemetry
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -58,7 +59,7 @@ def _fresh_state(gtree, comp, gamma0):
 
 def _run(gtree, mtree, gammas, comp, dl_state=None,
          mesh_shape=(W_WORKERS,), axes=("data",), eta=0.1):
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)

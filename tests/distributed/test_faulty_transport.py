@@ -31,11 +31,12 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.faults import FaultConfig, FaultCtx, guards_disabled
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
 from repro.core.telemetry import CompressionTelemetry
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -72,7 +73,7 @@ def _run(gtree, mtree, comp, transport, mesh_shape=(W_WORKERS,),
     ``fault_cfg`` wraps the transport in "faulty".  Returns
     (upd, new_mem, wire, eff, telemetry) — carried transport state (and
     the faulty wrapper's passthrough) is dropped inside the worker."""
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)
@@ -218,13 +219,13 @@ def _train_setup(transport, max_consecutive_skips=25):
     from repro.configs import get_smoke_config
     from repro.configs.base import OptimizerConfig, RunConfig, ShapeConfig
     from repro.core import ArmijoConfig
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.launch.train_step import (build_train_step, init_opt_state,
                                          opt_state_shardings)
     from repro.models import build_model
     from repro.sharding import param_shardings
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
     comp = Compressor(gamma=0.1, method="block_topk", block=256,
@@ -235,10 +236,12 @@ def _train_setup(transport, max_consecutive_skips=25):
             kind="csgd_asss", armijo=ArmijoConfig(), compressor=comp,
             transport=transport,
             max_consecutive_skips=max_consecutive_skips))
+    # uncommitted: an array made under set_mesh is committed replicated,
+    # which the step's batch-over-dp in_shardings reject
+    batch = {"tokens": jnp.ones((8, 32), jnp.int32)}
     with set_mesh(mesh):
         params = m.init(jax.random.PRNGKey(0))
         params = jax.device_put(params, param_shardings(params, mesh))
-        batch = {"tokens": jnp.ones((8, 32), jnp.int32)}
         st = init_opt_state(params, run, 4,
                             stacked_mask=m.stacked_mask(params))
         st = jax.device_put(st, opt_state_shardings(st, params, mesh, run))
@@ -252,7 +255,7 @@ def _run_steps(transport, guarded, n=2):
     execution (where jit actually traces) sit inside the context."""
     import contextlib
 
-    from repro.compat import set_mesh
+    from jax import set_mesh
 
     ctx = contextlib.nullcontext() if guarded else guards_disabled()
     with ctx:

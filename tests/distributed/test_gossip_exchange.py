@@ -30,13 +30,14 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.gossip import (GossipConfig, GossipCtx, GossipState,
                                gossip_mix)
 from repro.comm.topology import build_topology
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
 from repro.core.telemetry import CompressionTelemetry
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -53,7 +54,7 @@ def _worker_tree(key, n_workers=W_WORKERS):
 
 
 def _run_bucketed(gtree, mtree, comp, eta=0.1):
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     lead = jax.tree.map(lambda _: P("data"), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)
     tel_lead = jax.tree.map(lambda _: P("data"),
@@ -74,7 +75,7 @@ def _run_bucketed(gtree, mtree, comp, eta=0.1):
 
 
 def _run_gossip(gtree, mtree, comp, topology, eta=0.1):
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     topo = build_topology(topology, W_WORKERS)
     cfg = GossipConfig(topology=topology)
     lead = jax.tree.map(lambda _: P("data"), gtree)
@@ -162,7 +163,7 @@ def test_gossip_steps_match_mixing_matrix_simulation(key, topology):
     c = {"w": jax.random.normal(ks[2], (W_WORKERS, L, D)),
          "b": jax.random.normal(ks[3], (W_WORKERS, DB))}
 
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     lead = jax.tree.map(lambda _: P("data"), x0)
 
     def worker(x, m, v, tgt):
@@ -229,7 +230,7 @@ def test_gossip_steps_match_mixing_matrix_simulation(key, topology):
 
 
 def _one_mix_round(tree, topo, lr=1.0):
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     lead = jax.tree.map(lambda _: P("data"), tree)
 
     def w(t):

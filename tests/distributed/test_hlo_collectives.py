@@ -28,10 +28,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.bucket import build_bucket_plan
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 AG = '"stablehlo.all_gather"'
@@ -51,7 +52,7 @@ def _tree(key):
 
 
 def _lower_exchange(tree, comp, transport):
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     mem = jax.tree.map(jnp.zeros_like, tree)
     spec = jax.tree.map(lambda _: P(), tree)
     f = shard_map(
@@ -91,7 +92,7 @@ def _lower_downlink_exchange(tree, comp):
     from repro.comm.downlink import (DownlinkCtx, DownlinkResult,
                                      DownlinkState, init_downlink_state)
 
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     leaves = jax.tree.leaves(tree)
     dls = init_downlink_state([x.shape for x in leaves],
                               [x.ndim >= 2 for x in leaves], comp,
@@ -135,7 +136,7 @@ def _lower_gossip(tree, comp, topology):
     from repro.comm.gossip import GossipConfig, GossipCtx, GossipState
     from repro.comm.topology import build_topology
 
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     ctx = GossipCtx(topology=build_topology(topology, W_WORKERS),
                     cfg=GossipConfig(topology=topology),
                     state=GossipState.init(()))
@@ -170,7 +171,7 @@ def _lower_overlap(tree, comp, n_chunks, delay, mesh_shape=(W_WORKERS,),
     from repro.comm.overlap import (OverlapConfig, OverlapCtx,
                                     init_overlap_state)
 
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     leaves = jax.tree.leaves(tree)
     st = init_overlap_state([x.shape for x in leaves],
                             [x.ndim >= 2 for x in leaves], comp,
@@ -238,13 +239,13 @@ def _lower_train_step(transport, downlink="dense"):
     from repro.configs.base import (OptimizerConfig, RunConfig,
                                     ShapeConfig)
     from repro.core import ArmijoConfig
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.launch.train_step import (build_train_step, init_opt_state,
                                          opt_state_shardings)
     from repro.models import build_model
     from repro.sharding import param_shardings
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
     comp = Compressor(gamma=0.1, method="block_topk", block=256,
@@ -254,10 +255,12 @@ def _lower_train_step(transport, downlink="dense"):
         optimizer=OptimizerConfig(kind="csgd_asss", armijo=ArmijoConfig(),
                                   compressor=comp, transport=transport,
                                   downlink=downlink))
+    # uncommitted: an array made under set_mesh is committed replicated,
+    # which the step's batch-over-dp in_shardings reject
+    batch = {"tokens": jnp.zeros((8, 32), jnp.int32)}
     with set_mesh(mesh):
         params = m.init(jax.random.PRNGKey(0))
         params = jax.device_put(params, param_shardings(params, mesh))
-        batch = {"tokens": jnp.zeros((8, 32), jnp.int32)}
         st = init_opt_state(params, run, 4)
         st = jax.device_put(st, opt_state_shardings(st, params, mesh, run))
         step = build_train_step(m, run, mesh)(params, batch)
@@ -294,7 +297,7 @@ def test_train_step_downlink_keeps_collective_budget():
 def _lower_cohort(key, comp, n_clients):
     from repro.fed.clients import cohort_compress_aggregate
 
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     C = n_clients // W_WORKERS
     base = _tree(key)
     tree = jax.tree.map(
@@ -329,13 +332,13 @@ def _lower_fed_train_step(n_clients):
     from repro.configs.base import (FederatedConfig, OptimizerConfig,
                                     RunConfig, ShapeConfig)
     from repro.core import ArmijoConfig
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.launch.train_step import (build_train_step, init_opt_state,
                                          opt_state_shardings)
     from repro.models import build_model
     from repro.sharding import param_shardings
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
     comp = Compressor(gamma=0.1, method="block_topk", block=256,
@@ -345,11 +348,11 @@ def _lower_fed_train_step(n_clients):
         optimizer=OptimizerConfig(
             kind="csgd_asss", armijo=ArmijoConfig(), compressor=comp,
             federated=FederatedConfig(n_clients=n_clients)))
+    batch = {"tokens": jnp.zeros((n_clients, 1, 32), jnp.int32),
+             "participation": jnp.ones((n_clients,), jnp.float32)}
     with set_mesh(mesh):
         params = m.init(jax.random.PRNGKey(0))
         params = jax.device_put(params, param_shardings(params, mesh))
-        batch = {"tokens": jnp.zeros((n_clients, 1, 32), jnp.int32),
-                 "participation": jnp.ones((n_clients,), jnp.float32)}
         st = init_opt_state(params, run, 4)
         st = jax.device_put(st, opt_state_shardings(st, params, mesh, run))
         step = build_train_step(m, run, mesh)(params, batch)

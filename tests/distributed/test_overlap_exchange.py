@@ -24,12 +24,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.overlap import OverlapConfig, OverlapCtx, init_overlap_state
 from repro.comm.ring import ring_all_gather
 from repro.core import Compressor
 from repro.core.dcsgd import worker_compress_aggregate
 from repro.core.telemetry import CompressionTelemetry
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -68,7 +69,7 @@ def _run(gtree, mtree, gammas, comp, transport, cfg=None, state=None,
          mesh_shape=(W_WORKERS,), axes=("data",), eta=0.1):
     """One exchange; for the overlap transport also returns the new
     (W, ...)-batched carried state as a trailing element."""
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)
@@ -136,7 +137,7 @@ def test_ring_gather_matches_all_gather(mesh_shape, axes, n_chunks):
     rng = np.random.default_rng(7)
     payload = jnp.asarray(
         rng.integers(0, 2**32, (W_WORKERS, total_words), dtype=np.uint32))
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead = axes[0] if len(axes) == 1 else tuple(axes)
 
     def via_ring(p):

@@ -12,12 +12,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import wire as wire_fmt
 from repro.core import Compressor, tree_wire_bytes
 from repro.core.compression import block_extract_sparse
 from repro.core.dcsgd import (_per_layer_topk, _scatter_layers,
                               worker_compress_aggregate)
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -36,7 +37,7 @@ def _worker_tree(key, n_workers=W_WORKERS):
 def _run_workers(gtree, mtree, comp, eta=0.1, mesh_shape=(W_WORKERS,),
                  axes=("data",)):
     """worker_compress_aggregate under a real 8-way manual shard_map."""
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)
@@ -162,7 +163,7 @@ def test_gathered_buffer_is_the_accounted_bytes(key):
     d = 3000
     g = jax.random.normal(key, (d,))
     m = jnp.zeros((d,))
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
 
     def worker(g, m):
         return worker_compress_aggregate(g, m, jnp.float32(0.1), comp,

@@ -14,12 +14,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import wire as wire_fmt
 from repro.core import Compressor, tree_wire_bytes
 from repro.core.compression import block_extract_sparse
 from repro.core.dcsgd import (_per_layer_topk, _scatter_layers,
                               worker_compress_aggregate)
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -42,7 +43,7 @@ def _worker_gammas(comp, n_workers=W_WORKERS):
 def _run_workers(gtree, mtree, gammas, comp, eta=0.1):
     """worker_compress_aggregate under a real 8-way manual shard_map with a
     per-worker gamma_t carried in as a sharded (W,) array."""
-    mesh = jax.make_mesh((W_WORKERS,), ("data",))
+    mesh = make_mesh((W_WORKERS,), ("data",))
     lead = jax.tree.map(lambda _: P("data"), gtree)
     rep = jax.tree.map(lambda _: P(), gtree)
 

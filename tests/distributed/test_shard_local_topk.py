@@ -1,18 +1,16 @@
-"""ROADMAP open item: ``shard_local_topk`` on a real (4, 2) device mesh.
+"""``shard_local_topk`` on a real (4, 2) device mesh.
 
-On 0.4.x the nested manual-'model' shard_map SIGFPEs XLA (the training body
-is already fully manual there), so ``build_train_step`` degenerates
-shard-local selection to the direct call — which is semantically identical
-while the model axis is replicated.  This test pins the whole path end to
-end: with identical per-worker batches, one ``shard_local_topk`` DCSGD-ASSS
-step equals the single-device CSGD-ASSS step (the dense, paper-faithful
-reference), through the packed wire exchange.
+The selection runs in a nested shard_map manual over 'model' inside the
+train step's manual-dp region.  With identical per-worker batches, one
+``shard_local_topk`` DCSGD-ASSS step equals the single-device CSGD-ASSS
+step (the dense, paper-faithful reference), through the packed wire
+exchange.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.configs.base import OptimizerConfig, RunConfig, ShapeConfig
 from repro.core import ArmijoConfig, Compressor, CSGDConfig, csgd_asss
@@ -20,6 +18,7 @@ from repro.launch.train_step import (build_train_step, init_opt_state,
                                      opt_state_shardings)
 from repro.models import build_model
 from repro.sharding import param_shardings
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -42,7 +41,7 @@ def test_shard_local_topk_matches_single_device(key):
     """Same data on every worker: shard_local_topk DCSGD == single-node
     CSGD-ASSS (block_topk selection; block-aligned shards keep the
     block-local operator identical across the nesting)."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
     comp = Compressor(gamma=0.1, method="block_topk", block=256,
@@ -72,9 +71,9 @@ def test_shard_local_topk_matches_single_device(key):
 
 def test_shard_local_topk_equals_global_selection(key):
     """shard_local_topk=True and =False produce the SAME step while the
-    model axis is replicated (0.4.x fallback) or block-aligned (0.5+
-    nested path) — parity between the two build_train_step variants."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    model shards are block-aligned — parity between the two
+    build_train_step variants."""
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
     comp = Compressor(gamma=0.1, method="block_topk", block=256,

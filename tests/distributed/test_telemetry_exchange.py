@@ -12,12 +12,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import wire as wire_fmt
 from repro.core import Compressor
 from repro.core.compression import block_extract_sparse
 from repro.core.dcsgd import (_per_layer_topk, _scatter_layers,
                               worker_compress_aggregate)
+from repro.launch.mesh import make_mesh
 
 W_WORKERS = 8
 
@@ -40,7 +41,7 @@ def _run_workers(gtree, mtree, gammas, comp, eta=0.1,
                  mesh_shape=(W_WORKERS,), axes=("data",)):
     """Per-worker telemetry (leading worker axis) + the pmean aggregate,
     under a real 8-way manual shard_map with per-worker gamma_t."""
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     lead_axis = axes[0] if len(axes) == 1 else tuple(axes)
     lead = jax.tree.map(lambda _: P(lead_axis), gtree)
 

@@ -21,10 +21,11 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.bucket import build_bucket_plan
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import Compressor
 from repro.fed.clients import cohort_compress_aggregate, per_client_wire_bytes
 from repro.fed.sampling import participation_mask
+from repro.launch.mesh import make_mesh
 
 from reference import simulate_cohort
 
@@ -57,7 +58,7 @@ def _cohort(seed=0):
 def _run_mesh(mesh_name, grads, mem, eta_c, gamma_c, part, comp,
               aggregation):
     shape, axes = MESHES[mesh_name]
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     dp_axes = axes
     lead = P(axes)
     tlead = jax.tree.map(lambda _: lead, grads)

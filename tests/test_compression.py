@@ -6,6 +6,7 @@ import pytest
 
 from repro.core import (Compressor, block_threshold, contraction_gamma,
                         sparse_to_dense, topk_select, tree_wire_bytes)
+from repro.launch.mesh import make_mesh
 
 
 def test_topk_selects_largest_magnitudes(key):
@@ -87,20 +88,20 @@ def test_wire_bytes_accounting():
 
 def _run_worker(tree, comp, eta=0.1):
     """worker_compress_aggregate under a real 1-device shard_map (this also
-    exercises the compat axis_size path of ``_dp_size``)."""
+    exercises the lax.axis_size path of ``_dp_size``)."""
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.dcsgd import worker_compress_aggregate
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     mem = jax.tree.map(lambda x: jnp.zeros_like(x), tree)
     spec = jax.tree.map(lambda _: P(), tree)
     f = shard_map(
         partial(worker_compress_aggregate, comp=comp, dp_axes=("data",)),
         mesh=mesh, in_specs=(spec, spec, P()),
         out_specs=(spec, spec, P(), P(), P()),
-        axis_names={"data"})
+        axis_names={"data"}, check_vma=False)
     return jax.jit(f)(tree, mem, jnp.float32(eta))
 
 

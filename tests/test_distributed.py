@@ -36,12 +36,13 @@ def test_dcsgd_equals_csgd_same_data():
         from repro.configs.base import RunConfig, OptimizerConfig, ShapeConfig
         from repro.core import Compressor, ArmijoConfig, CSGDConfig, csgd_asss
         from repro.models import build_model
+        from repro.launch.mesh import make_mesh
         from repro.launch.train_step import build_train_step, init_opt_state, opt_state_shardings
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro.sharding import param_shardings
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke_config("qwen1.5-4b")
         m = build_model(cfg)
         comp = Compressor(gamma=0.1, min_compress_size=64)
@@ -86,13 +87,14 @@ def test_compressed_step_trains_and_saves_wire_bytes():
         from repro.configs.base import RunConfig, OptimizerConfig, ShapeConfig
         from repro.core import Compressor, ArmijoConfig
         from repro.models import build_model
+        from repro.launch.mesh import make_mesh
         from repro.launch.train_step import build_train_step, init_opt_state, opt_state_shardings
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro.sharding import param_shardings
         from repro.data.synthetic import TokenPipeline
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke_config("yi-34b")
         m = build_model(cfg)
         def mkrun(kind, gamma=0.05):
@@ -132,11 +134,12 @@ def test_decode_step_seq_sharded_cache_compiles():
         from repro.configs import get_smoke_config
         from repro.configs.base import RunConfig, OptimizerConfig, ShapeConfig
         from repro.models import build_model
+        from repro.launch.mesh import make_mesh
         from repro.launch.train_step import build_decode_step
-        from repro.compat import set_mesh
+        from jax import set_mesh
         import re
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke_config("yi-34b")
         m = build_model(cfg)
         shape = ShapeConfig("d", 256, 8, "decode")
@@ -149,8 +152,7 @@ def test_decode_step_seq_sharded_cache_compiles():
             co = step.lower(params_like, tok, cache_like, jnp.int32(255)).compile()
             txt = co.as_text()
             assert "all-reduce" in txt  # flash-decode combine over seq shards
-            from repro.compat import cost_analysis
-            print("DECODE_OK", cost_analysis(co).get("flops"))
+            print("DECODE_OK", co.cost_analysis().get("flops"))
     """)
 
 
@@ -176,10 +178,11 @@ def test_moe_expert_parallel_exact():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
-        from repro.compat import set_mesh
+        from jax import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as moe_mod
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(get_smoke_config("granite-moe-1b-a400m"),
                                   n_experts=8, experts_per_token=2,
                                   capacity_factor=4.0)
@@ -201,3 +204,41 @@ def test_moe_expert_parallel_exact():
         assert err < 1e-4, err
         print("EP_EXACT", err)
     """)
+
+
+def test_kernel_runs_manual_over_every_axis_in_train_region():
+    """dispatch's wrapper for compiled kernels (a shard_map manual over
+    every axis, replicated specs) inside the train step's region — manual
+    over 'data', auto over 'model' — keeps each dp worker's own operands
+    and static arguments, forward and backward."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro.kernels import dispatch
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((4, 2), ("data", "model"))
+        op = lambda x, none, w, k: (x * w + k, none)
+        x = jnp.arange(8 * 128, dtype=jnp.float32).reshape(8, 128)
+        w = jnp.linspace(0.5, 1.5, 128, dtype=jnp.float32)
+
+        def worker(x, w):
+            y, none = dispatch._manual_over_auto_axes(op, (x, None, w, 3), {})
+            assert none is None
+            g = jax.grad(lambda x: dispatch._manual_over_auto_axes(
+                op, (x, None, w, 3), {})[0].sum())(x)
+            return y, g
+
+        with jax.set_mesh(mesh):
+            y, g = jax.jit(jax.shard_map(
+                worker, in_specs=(P("data"), P()), out_specs=P("data"),
+                axis_names={"data"}, check_vma=False))(x, w)
+        # rtol: XLA may fuse or reorder the arithmetic (one rounding)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(x * w + 3),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(g),
+                                   np.broadcast_to(np.asarray(w), x.shape),
+                                   rtol=1e-6)
+        print("WRAP_OK")
+    """)
+    assert "WRAP_OK" in out

@@ -12,6 +12,7 @@ from repro.core import (ArmijoConfig, Compressor, CSGDConfig, csgd_asss,
                         topk_select)
 from repro.data.synthetic import interpolated_regression
 from repro.models import build_model
+from repro.launch.mesh import make_mesh
 
 
 def _problem(d=128, n=256, seed=0):
@@ -131,7 +132,7 @@ def test_local_steps_microbatch_mismatch_rejected_at_build_time():
 
     cfg = get_smoke_config("qwen1.5-4b")
     m = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def mkrun(local_steps, microbatches):
         return RunConfig(
@@ -165,13 +166,14 @@ def test_local_steps_distributed():
         from repro.configs.base import RunConfig, OptimizerConfig, ShapeConfig
         from repro.core import Compressor, ArmijoConfig
         from repro.models import build_model
+        from repro.launch.mesh import make_mesh
         from repro.launch.train_step import build_train_step, init_opt_state, opt_state_shardings
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro.sharding import param_shardings
         from repro.data.synthetic import TokenPipeline
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke_config("qwen1.5-4b")
         m = build_model(cfg)
         run = RunConfig(model=cfg, shape=ShapeConfig("t", 64, 8, "train"),

@@ -26,6 +26,7 @@ from repro.core import Compressor
 from repro.core.compression import block_extract_sparse
 from repro.core.health import (DivergenceError, HealthState, advance_health,
                                all_finite, check_divergence)
+from repro.launch.mesh import make_mesh
 
 from wire_fuzz import (check_garbage_bucket_decode_safe,
                        check_garbage_rows_decode_safe,
@@ -214,19 +215,19 @@ def test_faulty_wrapper_rejects_self_and_missing_inner_ctx():
 def _one_worker_exchange(transport, transport_ctx, comp, seed=0):
     """Jitted 1-worker worker_compress_aggregate under shard_map."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.dcsgd import worker_compress_aggregate
 
     rng = np.random.default_rng(seed)
     g = jnp.asarray(rng.standard_normal(D).astype(np.float32))
     m = jnp.asarray(rng.standard_normal(D).astype(np.float32)) * 0.5
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     f = shard_map(
         lambda gg, mm: worker_compress_aggregate(
             gg, mm, jnp.float32(0.25), comp, ("data",),
             transport=transport, transport_ctx=transport_ctx),
         mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-        axis_names={"data"})
+        axis_names={"data"}, check_vma=False)
     out = jax.jit(f)(g, m)
     return (g, m) + tuple(out)
 

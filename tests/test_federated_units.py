@@ -16,6 +16,7 @@ from repro.fed.aggregate import (scatter_with_support, support_weighted_mean,
 from repro.fed.clients import (cohort_compress_aggregate, init_client_state,
                                local_participation, per_client_wire_bytes)
 from repro.fed.sampling import ZeroParticipationError, participation_mask
+from repro.launch.mesh import make_mesh
 
 
 def _comp(**kw):
@@ -257,7 +258,7 @@ def test_build_train_step_rejects_bad_fed_combos():
 
     cfg = get_smoke_config("paper-lm-100m")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    mesh = make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
     shape = ShapeConfig("t", 16, 4, "train")
 

@@ -21,6 +21,7 @@ from repro.core import (ArmijoConfig, CompressionTelemetry, Compressor,
                         CSGDConfig, GammaControllerConfig, SearchTelemetry,
                         csgd_asss, gamma_init, gamma_update)
 from repro.data.synthetic import interpolated_regression
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # controller unit tests
@@ -320,7 +321,7 @@ def test_build_train_step_rejects_coupled_schedule_without_armijo():
     from repro.models import build_model
 
     cfg = smoke_variant(get_config("qwen1.5-4b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    mesh = make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
     run = RunConfig(
         model=cfg, shape=ShapeConfig("t", 64, 4, "train"),

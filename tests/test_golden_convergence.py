@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import (ArmijoConfig, Compressor, CSGDConfig,
                         GammaControllerConfig, csgd_asss)
 from repro.data.synthetic import interpolated_regression
+from repro.launch.mesh import make_mesh
 
 SEED = 0
 D = 256
@@ -168,7 +169,7 @@ def _burst_trajectory(fault_cfg=None, breaker=True, steps=600,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.comm.faults import FaultCtx
     from repro.core import armijo_search, next_alpha_max
     from repro.core.dcsgd import worker_compress_aggregate
@@ -177,7 +178,7 @@ def _burst_trajectory(fault_cfg=None, breaker=True, steps=600,
     bl = _quadratic_problem()
     comp = Compressor(gamma=GMAX, min_compress_size=1)
     acfg = ArmijoConfig(sigma=0.1, a_scale=0.3)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     faulty = fault_cfg is not None and fault_cfg.enabled
 
     def worker(w, m, amax, health, step, idx):
@@ -205,7 +206,7 @@ def _burst_trajectory(fault_cfg=None, breaker=True, steps=600,
 
     fn = jax.jit(shard_map(worker, mesh=mesh,
                            in_specs=(P(),) * 6, out_specs=P(),
-                           axis_names={"data"}))
+                           axis_names={"data"}, check_vma=False))
 
     @jax.jit
     def full_loss(w):

@@ -225,17 +225,18 @@ def test_wkv_kernel_sweep(key, S, K):
 
 def test_wkv_kernel_matches_time_mix_scan(key):
     """The kernel path of rwkv.time_mix == the scan path (same block)."""
-    import dataclasses
     from repro.configs import get_smoke_config
+    from repro.kernels import dispatch
     from repro.models import rwkv as rwkv_mod
     cfg = get_smoke_config("rwkv6-1.6b")
     p = rwkv_mod.init_rwkv6(key, cfg, jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.d_model))
     st = rwkv_mod.init_rwkv_state(cfg, 2)
-    y_scan, st_scan = rwkv_mod.time_mix(p, x, cfg, st)
-    cfg_k = dataclasses.replace(cfg, use_pallas=True)
+    with dispatch.using("ref"):
+        y_scan, st_scan = rwkv_mod.time_mix(p, x, cfg, st)
     st2 = rwkv_mod.init_rwkv_state(cfg, 2)
-    y_ker, st_ker = rwkv_mod.time_mix(p, x, cfg_k, st2)
+    with dispatch.using("pallas"):
+        y_ker, st_ker = rwkv_mod.time_mix(p, x, cfg, st2)
     np.testing.assert_allclose(np.asarray(y_scan), np.asarray(y_ker),
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(st_scan.wkv),

@@ -16,6 +16,7 @@ from repro.core.compression import block_extract_sparse
 from repro.core.error_feedback import dequantize_ef, quantize_ef
 from repro.kernels import ref
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 
 settings.register_profile("ci", max_examples=25, deadline=None)
 settings.load_profile("ci")
@@ -253,20 +254,20 @@ def _telemetry_fn(method: str, value_bits: int, adaptive: bool,
     """Jitted 1-worker worker_compress_aggregate -> CompressionTelemetry,
     cached per static config so hypothesis examples reuse compilations."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.dcsgd import worker_compress_aggregate
 
     comp = Compressor(gamma=_TEL_GAMMA,
                       max_gamma=_TEL_GAMMA if adaptive else 0.0,
                       method=method, block=256, min_compress_size=1,
                       value_bits=value_bits, use_kernel=use_kernel)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     f = shard_map(
         lambda g, m, eta, gt: worker_compress_aggregate(
             g, m, eta, comp, ("data",),
             gamma_t=gt if adaptive else None)[4],
         mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P(),
-        axis_names={"data"})
+        axis_names={"data"}, check_vma=False)
     return jax.jit(f)
 
 

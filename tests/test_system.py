@@ -87,3 +87,19 @@ def test_train_cli_runs(tmp_path):
     assert log and np.isfinite(log[-1]["loss"])
     from repro.checkpoint import checkpoint as ckpt
     assert ckpt.latest_step(str(tmp_path / "ck")) == 8
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """chip_smoke.py on a CPU-only JAX exits non-zero and prints no result
+    line, before it builds anything."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(repo, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=300, env=env,
+                       cwd=repo)
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout
+    assert "no TPU" in r.stderr

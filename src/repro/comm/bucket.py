@@ -197,48 +197,51 @@ def encode_buckets(plan: BucketPlan, rows, *,
     in-launch is applied here to the field sections before the batched
     stream pack (identical fields either way).
     """
-    secs: dict[int, tuple] = {}
-    for ln in plan.leaves:
-        if ln.dense:
-            continue
-        vals, idx, counts = rows[ln.index]
-        header, ifields, vfields, counts = wire_fmt.row_fields(
-            vals, idx, ln.spec, counts=counts)
-        if ln.spec.ragged:
-            valid = wire_fmt.field_mask(ln.spec.k, counts,
-                                        ln.spec.count_period)
-            ifields = jnp.where(valid, ifields, jnp.uint32(0))
-            vfields = jnp.where(valid, vfields, jnp.uint32(0))
-        secs[ln.index] = (header, ifields, vfields)
+    with jax.named_scope("csgd_codec"):
+        secs: dict[int, tuple] = {}
+        for ln in plan.leaves:
+            if ln.dense:
+                continue
+            vals, idx, counts = rows[ln.index]
+            header, ifields, vfields, counts = wire_fmt.row_fields(
+                vals, idx, ln.spec, counts=counts)
+            if ln.spec.ragged:
+                valid = wire_fmt.field_mask(ln.spec.k, counts,
+                                            ln.spec.count_period)
+                ifields = jnp.where(valid, ifields, jnp.uint32(0))
+                vfields = jnp.where(valid, vfields, jnp.uint32(0))
+            secs[ln.index] = (header, ifields, vfields)
 
-    lanes = {ln.index: ln for ln in plan.leaves}
-    iwords: dict[int, jax.Array] = {}
-    vwords: dict[int, jax.Array] = {}
-    for b in plan.buckets:
-        iwords.update(_pack_sections(
-            [(i, secs[i][1], lanes[i].spec.index_words) for i in b.leaf_ids],
-            b.index_bits, impl))
-        # value_bits is compressor-wide, so the value sections of every
-        # bucket share one width; keep the launch per bucket so the two
-        # stream shapes stay tied to the bucket geometry
-        vwords.update(_pack_sections(
-            [(i, secs[i][2], lanes[i].spec.value_words) for i in b.leaf_ids],
-            lanes[b.leaf_ids[0]].spec.value_bits, impl))
+        lanes = {ln.index: ln for ln in plan.leaves}
+        iwords: dict[int, jax.Array] = {}
+        vwords: dict[int, jax.Array] = {}
+        for b in plan.buckets:
+            iwords.update(_pack_sections(
+                [(i, secs[i][1], lanes[i].spec.index_words)
+                 for i in b.leaf_ids],
+                b.index_bits, impl))
+            # value_bits is compressor-wide, so the value sections of every
+            # bucket share one width; keep the launch per bucket so the two
+            # stream shapes stay tied to the bucket geometry
+            vwords.update(_pack_sections(
+                [(i, secs[i][2], lanes[i].spec.value_words)
+                 for i in b.leaf_ids],
+                lanes[b.leaf_ids[0]].spec.value_bits, impl))
 
-    segments = []
-    for ln in plan.leaves:
-        if ln.dense:
-            continue
-        header = secs[ln.index][0]
-        parts = ([header] if header is not None else [])
-        parts += [iwords[ln.index], vwords[ln.index]]
-        seg = jnp.concatenate(parts, axis=-1)
-        assert seg.shape == (ln.L, ln.spec.row_words), \
-            (seg.shape, ln.L, ln.spec.row_words)
-        segments.append(seg.reshape(-1))
-    payload = jnp.concatenate(segments)
-    assert payload.shape == (plan.total_words,)
-    return payload
+        segments = []
+        for ln in plan.leaves:
+            if ln.dense:
+                continue
+            header = secs[ln.index][0]
+            parts = ([header] if header is not None else [])
+            parts += [iwords[ln.index], vwords[ln.index]]
+            seg = jnp.concatenate(parts, axis=-1)
+            assert seg.shape == (ln.L, ln.spec.row_words), \
+                (seg.shape, ln.L, ln.spec.row_words)
+            segments.append(seg.reshape(-1))
+        payload = jnp.concatenate(segments)
+        assert payload.shape == (plan.total_words,)
+        return payload
 
 
 def decode_buckets(plan: BucketPlan, gathered: jax.Array, *,
@@ -260,81 +263,82 @@ def decode_buckets(plan: BucketPlan, gathered: jax.Array, *,
     on a clean wire every verdict is True and the decode is bit-exact
     vs ``with_verdicts=False``.
     """
-    W = gathered.shape[0]
-    lanes = {ln.index: ln for ln in plan.leaves}
-    pay: dict[int, jax.Array] = {}
-    for ln in plan.leaves:
-        if ln.dense:
-            continue
-        seg = gathered[:, ln.word_off:ln.word_off + ln.words]
-        rows = seg.reshape(W * ln.L, ln.spec.row_words)
-        pay[ln.index] = faults.maybe_corrupt(rows, ln.spec, ln.index, ln.L)
+    with jax.named_scope("csgd_codec"):
+        W = gathered.shape[0]
+        lanes = {ln.index: ln for ln in plan.leaves}
+        pay: dict[int, jax.Array] = {}
+        for ln in plan.leaves:
+            if ln.dense:
+                continue
+            seg = gathered[:, ln.word_off:ln.word_off + ln.words]
+            rows = seg.reshape(W * ln.L, ln.spec.row_words)
+            pay[ln.index] = faults.maybe_corrupt(rows, ln.spec, ln.index, ln.L)
 
-    ifields: dict[int, jax.Array] = {}
-    vfields: dict[int, jax.Array] = {}
-    for b in plan.buckets:
-        igroup, vgroup = [], []
-        for i in b.leaf_ids:
-            spec = lanes[i].spec
+        ifields: dict[int, jax.Array] = {}
+        vfields: dict[int, jax.Array] = {}
+        for b in plan.buckets:
+            igroup, vgroup = [], []
+            for i in b.leaf_ids:
+                spec = lanes[i].spec
+                off = spec.header_words
+                igroup.append((i, pay[i][:, off:off + spec.index_words],
+                               spec.k))
+                vgroup.append((i, pay[i][:, off + spec.index_words:
+                                         off + spec.index_words
+                                         + spec.value_words], spec.k))
+            ifields.update(_unpack_sections(igroup, b.index_bits, impl))
+            vfields.update(_unpack_sections(
+                vgroup, lanes[b.leaf_ids[0]].spec.value_bits, impl))
+
+        out = [None] * len(plan.leaves)
+        verdicts = [None] * len(plan.leaves)
+        by_spec: dict = {}
+        for ln in plan.leaves:
+            if ln.dense:
+                continue
+            spec, i = ln.spec, ln.index
+            counts = pay[i][:, 0].astype(jnp.int32) if spec.ragged else None
+            ifld, vfld = ifields[i], vfields[i]
+            if spec.ragged:
+                valid = wire_fmt.field_mask(spec.k, counts, spec.count_period)
+                ifld = jnp.where(valid, ifld, jnp.uint32(0))
+                vfld = jnp.where(valid, vfld, jnp.uint32(0))
             off = spec.header_words
-            igroup.append((i, pay[i][:, off:off + spec.index_words],
-                           spec.k))
-            vgroup.append((i, pay[i][:, off + spec.index_words:
-                                     off + spec.index_words
-                                     + spec.value_words], spec.k))
-        ifields.update(_unpack_sections(igroup, b.index_bits, impl))
-        vfields.update(_unpack_sections(
-            vgroup, lanes[b.leaf_ids[0]].spec.value_bits, impl))
-
-    out = [None] * len(plan.leaves)
-    verdicts = [None] * len(plan.leaves)
-    by_spec: dict = {}
-    for ln in plan.leaves:
-        if ln.dense:
-            continue
-        spec, i = ln.spec, ln.index
-        counts = pay[i][:, 0].astype(jnp.int32) if spec.ragged else None
-        ifld, vfld = ifields[i], vfields[i]
-        if spec.ragged:
-            valid = wire_fmt.field_mask(spec.k, counts, spec.count_period)
-            ifld = jnp.where(valid, ifld, jnp.uint32(0))
-            vfld = jnp.where(valid, vfld, jnp.uint32(0))
-        off = spec.header_words
-        scale_words = pay[i][:, off - 1:off] if spec.value_bits <= 8 \
-            else None
-        vals, idx = wire_fmt.fields_to_rows(ifld, vfld, scale_words,
-                                            counts, spec)
-        if with_verdicts:
-            by_spec.setdefault(spec, []).append((ln, vals, idx))
-        else:
-            out[i] = (vals.reshape(W, ln.L, spec.k),
-                      idx.reshape(W, ln.L, spec.k))
-    if not with_verdicts:
-        return out
-    # verdict + quarantine batch per WireSpec group, not per lane: every
-    # lane with the same row layout rides ONE fused launch (same
-    # coalescing argument as the bucket gather itself), keeping the §16
-    # guards inside the 1.05x guarded-vs-unguarded bench gate.  Row order
-    # is tree order within the concatenation, so slicing back per lane is
-    # bit-exact vs the per-lane calls.
-    for spec, members in by_spec.items():
-        if len(members) > 1:
-            cat_pay = jnp.concatenate(
-                [pay[ln.index] for ln, _, _ in members])
-            cat_vals = jnp.concatenate([v for _, v, _ in members])
-            cat_idx = jnp.concatenate([x for _, _, x in members])
-        else:
-            ln0 = members[0][0]
-            cat_pay, cat_vals, cat_idx = (pay[ln0.index], members[0][1],
-                                          members[0][2])
-        v = wire_fmt.row_verdict(cat_pay, spec, cat_vals, cat_idx)
-        cat_vals, cat_idx = wire_fmt.quarantine_rows(cat_vals, cat_idx, v)
-        off = 0
-        for ln, _, _ in members:
-            rows = W * ln.L
-            verdicts[ln.index] = v[off:off + rows].reshape(W, ln.L)
-            out[ln.index] = (
-                cat_vals[off:off + rows].reshape(W, ln.L, spec.k),
-                cat_idx[off:off + rows].reshape(W, ln.L, spec.k))
-            off += rows
-    return out, verdicts
+            scale_words = pay[i][:, off - 1:off] if spec.value_bits <= 8 \
+                else None
+            vals, idx = wire_fmt.fields_to_rows(ifld, vfld, scale_words,
+                                                counts, spec)
+            if with_verdicts:
+                by_spec.setdefault(spec, []).append((ln, vals, idx))
+            else:
+                out[i] = (vals.reshape(W, ln.L, spec.k),
+                          idx.reshape(W, ln.L, spec.k))
+        if not with_verdicts:
+            return out
+        # verdict + quarantine batch per WireSpec group, not per lane: every
+        # lane with the same row layout rides ONE fused launch (same
+        # coalescing argument as the bucket gather itself), keeping the §16
+        # guards inside the 1.05x guarded-vs-unguarded bench gate.  Row order
+        # is tree order within the concatenation, so slicing back per lane is
+        # bit-exact vs the per-lane calls.
+        for spec, members in by_spec.items():
+            if len(members) > 1:
+                cat_pay = jnp.concatenate(
+                    [pay[ln.index] for ln, _, _ in members])
+                cat_vals = jnp.concatenate([v for _, v, _ in members])
+                cat_idx = jnp.concatenate([x for _, _, x in members])
+            else:
+                ln0 = members[0][0]
+                cat_pay, cat_vals, cat_idx = (pay[ln0.index], members[0][1],
+                                              members[0][2])
+            v = wire_fmt.row_verdict(cat_pay, spec, cat_vals, cat_idx)
+            cat_vals, cat_idx = wire_fmt.quarantine_rows(cat_vals, cat_idx, v)
+            off = 0
+            for ln, _, _ in members:
+                rows = W * ln.L
+                verdicts[ln.index] = v[off:off + rows].reshape(W, ln.L)
+                out[ln.index] = (
+                    cat_vals[off:off + rows].reshape(W, ln.L, spec.k),
+                    cat_idx[off:off + rows].reshape(W, ln.L, spec.k))
+                off += rows
+        return out, verdicts

@@ -103,11 +103,12 @@ def gather_packed(payload: jax.Array, dp_axes: AxisNames, *,
     result, same total bytes per link, but split into ``n_chunks * (W-1)``
     small dependency-free collectives an overlap-capable runtime can hide
     behind compute."""
-    if ring_chunks is not None:
-        from repro.comm.ring import ring_all_gather
-        flat = ring_all_gather(payload.reshape(-1), dp_axes, ring_chunks)
-        return flat.reshape(-1, *payload.shape)
-    gathered = jax.lax.all_gather(payload, dp_axes)
-    if isinstance(dp_axes, (tuple, list)) and len(dp_axes) > 1:
-        gathered = gathered.reshape(-1, *payload.shape)
-    return gathered
+    with jax.named_scope("csgd_codec"):
+        if ring_chunks is not None:
+            from repro.comm.ring import ring_all_gather
+            flat = ring_all_gather(payload.reshape(-1), dp_axes, ring_chunks)
+            return flat.reshape(-1, *payload.shape)
+        gathered = jax.lax.all_gather(payload, dp_axes)
+        if isinstance(dp_axes, (tuple, list)) and len(dp_axes) > 1:
+            gathered = gathered.reshape(-1, *payload.shape)
+        return gathered

@@ -194,40 +194,48 @@ def _consume_decoded_leaf(g, m, g2f, g_vals, g_idx, spec, L, d, count, W,
     for the round (the payload never reached anyone intact; re-sending
     the whole accumulator next round is the EF-correct response).
     """
-    total = _scatter_layers(g_vals, g_idx, L, d, jnp.float32)
-    if verdict is None:
-        mean_dense = total / W
-    else:
-        # the §13 support-weighted division without its 0/0 `where`:
-        # quarantined rows scatter zero mass, so an all-invalid layer has
-        # an all-zero total and /max(s,1) already answers 0 — one fewer
-        # (L, d) pass on the always-on clean path (1.05x bench gate)
-        n_valid = jnp.sum(verdict.astype(jnp.float32), axis=0)     # (L,)
-        mean_dense = total / jnp.maximum(n_valid[:, None], 1.0)
+    # decoding the gathered rows into the dense mean is the codec's; the
+    # own rows' residual and its telemetry are the EF memory update
+    with jax.named_scope("csgd_codec"):
+        total = _scatter_layers(g_vals, g_idx, L, d, jnp.float32)
+        if verdict is None:
+            mean_dense = total / W
+        else:
+            # the §13 support-weighted division without its 0/0 `where`:
+            # quarantined rows scatter zero mass, so an all-invalid layer
+            # has an all-zero total and /max(s,1) already answers 0 — one
+            # fewer (L, d) pass on the always-on clean path (1.05x gate)
+            n_valid = jnp.sum(verdict.astype(jnp.float32), axis=0)  # (L,)
+            mean_dense = total / jnp.maximum(n_valid[:, None], 1.0)
+        upd = mean_dense.reshape(g.shape)
     wire_add = jnp.float32(L * spec.row_bytes)
     eff_add = (jnp.float32(L) * spec.effective_row_bytes(count)
                if spec.ragged else jnp.float32(L * spec.row_bytes))
-    w_idx = _dp_index(dp_axes)
-    own_vals = jax.lax.dynamic_index_in_dim(g_vals, w_idx, 0,
-                                            keepdims=False)
-    own_idx = jax.lax.dynamic_index_in_dim(g_idx, w_idx, 0, keepdims=False)
-    own_dense = _scatter_layers(own_vals, own_idx, L, d, jnp.float32)
-    if use_fused:
-        r = resid + (sent - own_dense)
-    else:
-        r = acc2 - own_dense
-    quar = jnp.float32(0.0)
-    if verdict is not None:
-        own_ok = jax.lax.dynamic_index_in_dim(verdict, w_idx, 0,
-                                              keepdims=False)       # (L,)
-        m2f = m.astype(jnp.float32).reshape(L, d)
-        r = jnp.where(own_ok[:, None], r, m2f)
-        quar = jnp.float32(verdict.size) - jnp.sum(n_valid)
-    # telemetry: the decoded-side sums touch only the k wire entries;
-    # sum m'^2 fuses into the residual's own materialization above
-    leaf_own_sq, leaf_dot = sparse_own_sums(own_vals, own_idx, g2f)
-    return (mean_dense.reshape(g.shape), r.reshape(m.shape).astype(m.dtype),
-            wire_add, eff_add, jnp.sum(r * r), leaf_own_sq, leaf_dot, quar)
+    with jax.named_scope("csgd_ef"):
+        w_idx = _dp_index(dp_axes)
+        own_vals = jax.lax.dynamic_index_in_dim(g_vals, w_idx, 0,
+                                                keepdims=False)
+        own_idx = jax.lax.dynamic_index_in_dim(g_idx, w_idx, 0,
+                                               keepdims=False)
+        own_dense = _scatter_layers(own_vals, own_idx, L, d, jnp.float32)
+        if use_fused:
+            r = resid + (sent - own_dense)
+        else:
+            r = acc2 - own_dense
+        quar = jnp.float32(0.0)
+        if verdict is not None:
+            own_ok = jax.lax.dynamic_index_in_dim(verdict, w_idx, 0,
+                                                  keepdims=False)   # (L,)
+            m2f = m.astype(jnp.float32).reshape(L, d)
+            r = jnp.where(own_ok[:, None], r, m2f)
+            quar = jnp.float32(verdict.size) - jnp.sum(n_valid)
+        # telemetry: the decoded-side sums touch only the k wire entries;
+        # sum m'^2 fuses into the residual's own materialization above
+        leaf_own_sq, leaf_dot = sparse_own_sums(own_vals, own_idx, g2f)
+        mem_leaf = r.reshape(m.shape).astype(m.dtype)
+        resid_sq = jnp.sum(r * r)
+    return (upd, mem_leaf, wire_add, eff_add, resid_sq, leaf_own_sq,
+            leaf_dot, quar)
 
 
 @register_transport("perleaf", description=(
@@ -246,66 +254,69 @@ def _perleaf_exchange(flat_g, flat_m, flat_s, eta, comp, dp_axes, gamma_t,
         g2 = _leaf_2d(g, stacked)
         L, d = g2.shape
         if comp.ships_dense(d):
-            acc = m.astype(jnp.float32) + eta * g.astype(jnp.float32)
-            upd = jax.lax.pmean(acc, dp_axes)
+            with jax.named_scope("csgd_codec"):
+                acc = m.astype(jnp.float32) + eta * g.astype(jnp.float32)
+                upd = jax.lax.pmean(acc, dp_axes)
             updates.append(upd)
             new_mem.append(jnp.zeros_like(m))
             wire = wire + jnp.float32(acc.size * acc.dtype.itemsize)
             eff_wire = eff_wire + jnp.float32(acc.size * acc.dtype.itemsize)
             sums = sums.add_dense(acc, g)
             continue
-        g2f = g2.astype(jnp.float32)
-        if use_fused:
-            # fused two-pass Pallas path (DESIGN.md §3): pass 1 streams
-            # (m, g) once for the per-block k_b-th |m + eta*g| statistic
-            # AND the dense telemetry moments (sum g^2, sum acc^2) on the
-            # same resident tile; pass 2 streams them again and writes
-            # (sent, m') — the accumulator never round-trips through HBM.
-            m2 = _leaf_2d(m, stacked).astype(jnp.float32)
-            # threshold at the BUDGET level (geometry_gamma == max_gamma
-            # for adaptive compressors): block_extract_sparse below pulls
-            # exactly block_k() budget entries per block, and any
-            # per-round k_t mask is applied at encode time
-            sent, resid, _, moments = ops.fused_ef_compress(
-                m2, g2f, eta, comp.geometry_gamma, comp.block,
-                telemetry=True)
-            leaf_g_sq = jnp.sum(moments[:, 0])
-            leaf_acc_sq = jnp.sum(moments[:, 1])
-            # per-block top-k_b of |sent| recovers the kept wire entries
-            # (>= k_b survive the threshold; ties beyond k_b are dropped
-            # from the wire and recycled into m' below)
-            vals, idx = block_extract_sparse(sent, comp)
-        else:
-            acc2 = _leaf_2d(m, stacked).astype(jnp.float32) + eta * g2f
-            leaf_g_sq = jnp.sum(g2f * g2f)
-            leaf_acc_sq = jnp.sum(acc2 * acc2)
-            vals, idx, (L, d) = compress_leaf(acc2, comp, stacked)
+        with jax.named_scope("csgd_ef"):
+            g2f = g2.astype(jnp.float32)
+            if use_fused:
+                # fused two-pass Pallas path (DESIGN.md §3): pass 1 streams
+                # (m, g) once for the per-block k_b-th |m + eta*g| statistic
+                # AND the dense telemetry moments (sum g^2, sum acc^2) on the
+                # same resident tile; pass 2 streams them again and writes
+                # (sent, m') — the accumulator never round-trips through HBM.
+                m2 = _leaf_2d(m, stacked).astype(jnp.float32)
+                # threshold at the BUDGET level (geometry_gamma == max_gamma
+                # for adaptive compressors): block_extract_sparse below pulls
+                # exactly block_k() budget entries per block, and any
+                # per-round k_t mask is applied at encode time
+                sent, resid, _, moments = ops.fused_ef_compress(
+                    m2, g2f, eta, comp.geometry_gamma, comp.block,
+                    telemetry=True)
+                leaf_g_sq = jnp.sum(moments[:, 0])
+                leaf_acc_sq = jnp.sum(moments[:, 1])
+                # per-block top-k_b of |sent| recovers the kept wire entries
+                # (>= k_b survive the threshold; ties beyond k_b are dropped
+                # from the wire and recycled into m' below)
+                vals, idx = block_extract_sparse(sent, comp)
+            else:
+                acc2 = _leaf_2d(m, stacked).astype(jnp.float32) + eta * g2f
+                leaf_g_sq = jnp.sum(g2f * g2f)
+                leaf_acc_sq = jnp.sum(acc2 * acc2)
+                vals, idx, (L, d) = compress_leaf(acc2, comp, stacked)
 
         # ---- bit-packed wire (DESIGN.md §8): encode once, gather ONE
         # uint32 payload per leaf — the payload's byte length is exactly
         # Compressor.wire_bytes (checked at trace time below), and the EF
         # residual is taken against what receivers actually decode, so
         # quantization error AND tie-dropped entries are recycled.
-        spec = wire_fmt.WireSpec.for_row(comp, d)
-        # per-round valid count (DESIGN.md §9): entries past it are
-        # masked out of the payload behind the count header word
-        count = _leaf_count(comp, spec, gamma_t, d)
-        counts = None if count is None else jnp.broadcast_to(count, (L,))
-        payload = wire_fmt.encode_rows(vals, idx, spec, counts=counts)
-        check_payload(payload, spec, comp, d)
+        with jax.named_scope("csgd_codec"):
+            spec = wire_fmt.WireSpec.for_row(comp, d)
+            # per-round valid count (DESIGN.md §9): entries past it are
+            # masked out of the payload behind the count header word
+            count = _leaf_count(comp, spec, gamma_t, d)
+            counts = None if count is None else jnp.broadcast_to(count, (L,))
+            payload = wire_fmt.encode_rows(vals, idx, spec, counts=counts)
+            check_payload(payload, spec, comp, d)
 
-        all_pay = gather_packed(payload, dp_axes)        # (W, L, words)
-        all_rows = faults.maybe_corrupt(
-            all_pay.reshape(-1, spec.row_words), spec, leaf_i, L)
-        g_vals, g_idx = wire_fmt.decode_rows(all_rows, spec)
-        verdict = None
-        if faults.guards_active():
-            verdict = wire_fmt.row_verdict(all_rows, spec, g_vals, g_idx)
-            g_vals, g_idx = wire_fmt.quarantine_rows(g_vals, g_idx,
-                                                     verdict)
-            verdict = verdict.reshape(W, L)
-        g_vals = g_vals.reshape(W, L, spec.k)
-        g_idx = g_idx.reshape(W, L, spec.k)
+            all_pay = gather_packed(payload, dp_axes)        # (W, L, words)
+            all_rows = faults.maybe_corrupt(
+                all_pay.reshape(-1, spec.row_words), spec, leaf_i, L)
+            g_vals, g_idx = wire_fmt.decode_rows(all_rows, spec)
+            verdict = None
+            if faults.guards_active():
+                verdict = wire_fmt.row_verdict(all_rows, spec, g_vals, g_idx)
+                g_vals, g_idx = wire_fmt.quarantine_rows(g_vals, g_idx,
+                                                         verdict)
+                verdict = verdict.reshape(W, L)
+            g_vals = g_vals.reshape(W, L, spec.k)
+            g_idx = g_idx.reshape(W, L, spec.k)
         (upd, mem_leaf, wire_add, eff_add, resid_sq, own_sq, own_dot,
          quar) = _consume_decoded_leaf(
                 g, m, g2f, g_vals, g_idx, spec, L, d, count, W, dp_axes,
@@ -374,19 +385,20 @@ def _bucketed_exchange(flat_g, flat_m, flat_s, eta, comp, dp_axes, gamma_t,
     dense_acc = [None] * n
     dense_mean = [None] * n
     dense_ids = list(plan.dense_ids)
-    for i in dense_ids:
-        dense_acc[i] = flat_m[i].astype(jnp.float32) \
-            + eta * flat_g[i].astype(jnp.float32)
-    if dense_ids:
-        mean_cat = jax.lax.pmean(
-            jnp.concatenate([dense_acc[i].reshape(-1) for i in dense_ids]),
-            dp_axes)
-        off = 0
+    with jax.named_scope("csgd_codec"):
         for i in dense_ids:
-            size = dense_acc[i].size
-            dense_mean[i] = mean_cat[off:off + size].reshape(
-                dense_acc[i].shape)
-            off += size
+            dense_acc[i] = flat_m[i].astype(jnp.float32) \
+                + eta * flat_g[i].astype(jnp.float32)
+        if dense_ids:
+            mean_cat = jax.lax.pmean(
+                jnp.concatenate([dense_acc[i].reshape(-1)
+                                 for i in dense_ids]), dp_axes)
+            off = 0
+            for i in dense_ids:
+                size = dense_acc[i].size
+                dense_mean[i] = mean_cat[off:off + size].reshape(
+                    dense_acc[i].shape)
+                off += size
 
     # ---- per-leaf consumers, ORIGINAL tree order (the f32 accumulation
     # order of the byte counters and telemetry sums is part of the
@@ -431,8 +443,10 @@ def dense_aggregate(grads: PyTree, eta: jax.Array,
     actually moves — the same ``size * dtype.itemsize`` basis the
     transports charge their dense leaves, so the two accountings cannot
     drift (they used to: this path hard-coded 4 bytes/element)."""
-    upd = jax.tree.map(
-        lambda g: jax.lax.pmean(eta * g.astype(jnp.float32), dp_axes), grads)
-    wire = jnp.float32(sum(u.size * u.dtype.itemsize
-                           for u in jax.tree.leaves(upd)))
-    return upd, wire
+    with jax.named_scope("csgd_codec"):
+        upd = jax.tree.map(
+            lambda g: jax.lax.pmean(eta * g.astype(jnp.float32), dp_axes),
+            grads)
+        wire = jnp.float32(sum(u.size * u.dtype.itemsize
+                               for u in jax.tree.leaves(upd)))
+        return upd, wire

@@ -122,43 +122,44 @@ def select_and_encode(flat_g, flat_m, flat_s, eta, comp: Compressor,
     is per leaf BY DESIGN — the contraction constant is per layer row;
     only the collective schedule differs between transports.
     """
-    use_fused = comp.method == "block_topk" and comp.use_kernel
-    lanes = plan.leaves
-    n = len(lanes)
-    comp_ids = list(plan.compressed_ids)
-    sel = Selection(use_fused, *([None] * n for _ in range(8)))
-    if use_fused and comp_ids:
-        ms = [leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32)
-              for i in comp_ids]
-        gs = [leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
-              for i in comp_ids]
-        # one pass-1 + one pass-2 launch for ALL leaves; thresholds stay
-        # at the BUDGET level exactly as in the per-leaf path
-        outs = ops.fused_ef_compress_batched(
-            ms, gs, eta, comp.geometry_gamma, comp.block, telemetry=True)
-        for i, g2, (s, r, _, moments) in zip(comp_ids, gs, outs):
-            sel.g2f[i], sel.sent[i], sel.resid[i] = g2, s, r
-            # NB: the batched kernel's per-leaf outputs are bit-identical
-            # to per-leaf launches, but THIS reduce may fuse differently
-            # in the two programs — XLA does not pin f32 reduction order
-            # across program shapes, so telemetry parity is a few-ulp
-            # contract while every other output is bit-exact (DESIGN §11)
-            sel.leaf_g_sq[i] = jnp.sum(moments[:, 0])
-            sel.leaf_acc_sq[i] = jnp.sum(moments[:, 1])
-    for i in comp_ids:
-        lane = lanes[i]
-        if use_fused:
-            vals, idx = block_extract_sparse(sel.sent[i], comp)
-        else:
-            g2 = leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
-            a2 = leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32) \
-                + eta * g2
-            sel.g2f[i], sel.acc2[i] = g2, a2
-            sel.leaf_g_sq[i] = jnp.sum(g2 * g2)
-            sel.leaf_acc_sq[i] = jnp.sum(a2 * a2)
-            vals, idx, _ = compress_leaf(a2, comp, flat_s[i])
-        sel.counts[i] = leaf_count(comp, lane.spec, gamma_t, lane.d)
-        sel.enc_rows[i] = (vals, idx,
-                           None if sel.counts[i] is None
-                           else jnp.broadcast_to(sel.counts[i], (lane.L,)))
-    return sel
+    with jax.named_scope("csgd_ef"):
+        use_fused = comp.method == "block_topk" and comp.use_kernel
+        lanes = plan.leaves
+        n = len(lanes)
+        comp_ids = list(plan.compressed_ids)
+        sel = Selection(use_fused, *([None] * n for _ in range(8)))
+        if use_fused and comp_ids:
+            ms = [leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32)
+                  for i in comp_ids]
+            gs = [leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
+                  for i in comp_ids]
+            # one pass-1 + one pass-2 launch for ALL leaves; thresholds stay
+            # at the BUDGET level exactly as in the per-leaf path
+            outs = ops.fused_ef_compress_batched(
+                ms, gs, eta, comp.geometry_gamma, comp.block, telemetry=True)
+            for i, g2, (s, r, _, moments) in zip(comp_ids, gs, outs):
+                sel.g2f[i], sel.sent[i], sel.resid[i] = g2, s, r
+                # NB: the batched kernel's per-leaf outputs are bit-identical
+                # to per-leaf launches, but THIS reduce may fuse differently
+                # in the two programs — XLA does not pin f32 reduction order
+                # across program shapes, so telemetry parity is a few-ulp
+                # contract while every other output is bit-exact (DESIGN §11)
+                sel.leaf_g_sq[i] = jnp.sum(moments[:, 0])
+                sel.leaf_acc_sq[i] = jnp.sum(moments[:, 1])
+        for i in comp_ids:
+            lane = lanes[i]
+            if use_fused:
+                vals, idx = block_extract_sparse(sel.sent[i], comp)
+            else:
+                g2 = leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
+                a2 = leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32) \
+                    + eta * g2
+                sel.g2f[i], sel.acc2[i] = g2, a2
+                sel.leaf_g_sq[i] = jnp.sum(g2 * g2)
+                sel.leaf_acc_sq[i] = jnp.sum(a2 * a2)
+                vals, idx, _ = compress_leaf(a2, comp, flat_s[i])
+            sel.counts[i] = leaf_count(comp, lane.spec, gamma_t, lane.d)
+            sel.enc_rows[i] = (vals, idx,
+                               None if sel.counts[i] is None
+                               else jnp.broadcast_to(sel.counts[i], (lane.L,)))
+        return sel

@@ -2,7 +2,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
-the production meshes and extract the roofline raw terms.
+the production meshes and extract the raw per-chip cost terms.
 
 MUST be run as its own process (the XLA flag above must precede any jax
 import — which is why it is the very first statement of the module).
@@ -275,8 +275,8 @@ def make_run_config(cfg, shape, opt_kind="csgd_asss", gamma=0.01,
         microbatches = 1   # each client IS a batch row group
     # max_backtracks=2 pins the Armijo while loop's HLO trip-count constant
     # to the paper's expected ~2 condition evaluations per step (we measure
-    # 1.7-1.9 on real runs), so the trip-count-aware roofline charges the
-    # search its EXPECTED cost.  Execution semantics on TPU are unchanged
+    # 1.7-1.9 on real runs), so the trip-count-aware cost analysis charges
+    # the search its EXPECTED cost.  Execution semantics on TPU are unchanged
     # apart from the iteration cap (dynamic early exit still applies).
     return RunConfig(
         model=cfg, shape=shape,

@@ -13,6 +13,8 @@ writes checkpoints.  ``--arch paper-lm-100m`` is the ~100M end-to-end run.
 that must keep the chip in one process (``chip_smoke.py``) drives it
 in-process.  Compiled programs persist in JAX's compilation cache:
 ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
+``--profile-dir DIR --profile-steps A:B`` writes a ``jax.profiler`` trace
+of steps A to B - 1 with the loop's host spans (``launch/spans.py``).
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ from repro.core.health import check_divergence
 from repro.data.synthetic import TokenPipeline
 from repro.fed.sampling import participation_mask
 from repro.launch.mesh import parse_mesh
+from repro.launch.spans import DEFAULT_STEPS, LoopProfile, parse_steps
 from repro.launch.train_step import (build_train_step, init_opt_state,
                                      opt_state_shardings)
 from repro.models import build_model
@@ -238,6 +241,15 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out", default=None, help="JSON metrics log")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a jax.profiler trace of --profile-steps "
+                         "here, with the loop's host spans (train.step, "
+                         "train.make_batch, train.put_batch, train.wait, "
+                         "train.divergence_read, train.log, "
+                         "train.checkpoint)")
+    ap.add_argument("--profile-steps", type=parse_steps,
+                    default=DEFAULT_STEPS, metavar="A:B",
+                    help="the traced steps, A to B - 1 (default 1:3)")
     return ap.parse_args(argv)
 
 
@@ -294,6 +306,31 @@ def run_config(args: argparse.Namespace) -> RunConfig:
                                quarantine=not args.no_quarantine),
             max_consecutive_skips=args.max_consecutive_skips),
         microbatches=args.microbatches)
+
+
+def log_row(metrics: dict, step: int, t_start: float,
+            args: argparse.Namespace) -> dict:
+    """The step's metrics read to the host, printed as one line."""
+    m = {k: float(v) for k, v in metrics.items()}
+    m["step"] = step
+    m["wall_s"] = round(time.time() - t_start, 1)
+    down = (f"down={m['downlink_effective_wire_bytes']:.3e}B "
+            if "downlink_effective_wire_bytes" in m else "")
+    print(f"step {step:5d} loss={m['loss']:.4f} "
+          f"alpha={m['alpha']:.4g} evals={m['n_evals']:.2f} "
+          f"up={m['wire_bytes']:.3e}B "
+          f"eff={m.get('effective_wire_bytes', 0.0):.3e}B "
+          f"{down}"
+          f"cum={m.get('cum_effective_wire_bytes', 0.0):.3e}B "
+          f"gamma={m.get('gamma', args.gamma):.4g} "
+          f"backlog={m.get('ef_backlog', 0.0):.3g} "
+          f"cos={m.get('ef_cosine', 1.0):.3f}"
+          + (f" skips={m['steps_skipped']:.0f}"
+             f" quar={m['rows_quarantined']:.0f}"
+             if m.get("steps_skipped", 0.0)
+             or m.get("rows_quarantined", 0.0) else ""),
+          flush=True)
+    return m
 
 
 def main(argv: list[str] | None = None) -> dict:
@@ -369,50 +406,44 @@ def main(argv: list[str] | None = None) -> dict:
         compile_s = 0.0
         step_s = []
         t_start = time.time()
-        for step in range(start, args.steps):
-            batch = put_batch(make_batch(step))
-            if step_fn is None:
-                step_fn = build_train_step(model, run, mesh)(params, batch)
-                t0 = time.perf_counter()
-                step_fn = step_fn.lower(params, opt_state, batch).compile()
-                compile_s = time.perf_counter() - t0
-                print(f"compiled train_step in {compile_s:.1f}s")
-            t0 = time.perf_counter()
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-            jax.block_until_ready(metrics)
-            step_s.append(time.perf_counter() - t0)
-            if run.optimizer.max_consecutive_skips > 0:
-                # host-side breaker: DivergenceError is a typed Python
-                # exception, impossible to raise from inside jit
-                check_divergence(
-                    {"step": step,
-                     "consecutive_skips": metrics["consecutive_skips"],
-                     "last_good_step": metrics["last_good_step"]},
-                    run.optimizer.max_consecutive_skips)
-            if step % args.log_every == 0 or step == args.steps - 1:
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = step
-                m["wall_s"] = round(time.time() - t_start, 1)
-                log.append(m)
-                down = (f"down={m['downlink_effective_wire_bytes']:.3e}B "
-                        if "downlink_effective_wire_bytes" in m else "")
-                print(f"step {step:5d} loss={m['loss']:.4f} "
-                      f"alpha={m['alpha']:.4g} evals={m['n_evals']:.2f} "
-                      f"up={m['wire_bytes']:.3e}B "
-                      f"eff={m.get('effective_wire_bytes', 0.0):.3e}B "
-                      f"{down}"
-                      f"cum={m.get('cum_effective_wire_bytes', 0.0):.3e}B "
-                      f"gamma={m.get('gamma', args.gamma):.4g} "
-                      f"backlog={m.get('ef_backlog', 0.0):.3g} "
-                      f"cos={m.get('ef_cosine', 1.0):.3f}"
-                      + (f" skips={m['steps_skipped']:.0f}"
-                         f" quar={m['rows_quarantined']:.0f}"
-                         if m.get("steps_skipped", 0.0)
-                         or m.get("rows_quarantined", 0.0) else ""),
-                      flush=True)
-            if args.ckpt_dir and step and step % args.ckpt_every == 0:
-                ckpt.save(args.ckpt_dir, step, (params, opt_state),
-                          metadata={"step": step})
+        with LoopProfile(args.profile_dir, args.profile_steps) as prof:
+            for step in range(start, args.steps):
+                with prof.step(step):
+                    with prof.span("train.make_batch"):
+                        host_batch = make_batch(step)
+                    with prof.span("train.put_batch"):
+                        batch = put_batch(host_batch)
+                    if step_fn is None:
+                        step_fn = build_train_step(model, run, mesh)(
+                            params, batch)
+                        t0 = time.perf_counter()
+                        step_fn = step_fn.lower(params, opt_state,
+                                                batch).compile()
+                        compile_s = time.perf_counter() - t0
+                        print(f"compiled train_step in {compile_s:.1f}s")
+                    t0 = time.perf_counter()
+                    params, opt_state, metrics = step_fn(params, opt_state,
+                                                         batch)
+                    with prof.span("train.wait"):
+                        jax.block_until_ready(metrics)
+                    step_s.append(time.perf_counter() - t0)
+                    if run.optimizer.max_consecutive_skips > 0:
+                        # host-side breaker: DivergenceError is a typed
+                        # Python exception, impossible to raise from jit
+                        with prof.span("train.divergence_read"):
+                            check_divergence(
+                                {"step": step,
+                                 "consecutive_skips":
+                                     metrics["consecutive_skips"],
+                                 "last_good_step": metrics["last_good_step"]},
+                                run.optimizer.max_consecutive_skips)
+                    if step % args.log_every == 0 or step == args.steps - 1:
+                        with prof.span("train.log"):
+                            log.append(log_row(metrics, step, t_start, args))
+                    if args.ckpt_dir and step and step % args.ckpt_every == 0:
+                        with prof.span("train.checkpoint"):
+                            ckpt.save(args.ckpt_dir, step, (params, opt_state),
+                                      metadata={"step": step})
         if args.ckpt_dir:
             ckpt.save(args.ckpt_dir, args.steps, (params, opt_state),
                       metadata={"step": args.steps})

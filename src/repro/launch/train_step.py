@@ -382,15 +382,19 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
 
         def one(carry, mb):
             p_loc, amax, ev = carry
-            loss, g = jax.value_and_grad(local_loss)(p_loc, mb)
-            gsq = tree_sqnorm(g)
-            res = armijo_search(lambda p: local_loss(p, mb), p_loc, g,
-                                amax, opt.armijo, f0=loss, grad_sqnorm=gsq)
+            with jax.named_scope("csgd_grad"):
+                loss, g = jax.value_and_grad(local_loss)(p_loc, mb)
+                gsq = tree_sqnorm(g)
+            with jax.named_scope("csgd_armijo"):
+                res = armijo_search(lambda p: local_loss(p, mb), p_loc, g,
+                                    amax, opt.armijo, f0=loss,
+                                    grad_sqnorm=gsq)
             eta = opt.armijo.a_scale * res.alpha
-            p_loc = jax.tree.map(
-                lambda p, gg: (p.astype(jnp.float32)
-                               - eta * gg.astype(jnp.float32)).astype(p.dtype),
-                p_loc, g)
+            with jax.named_scope("csgd_apply"):
+                p_loc = jax.tree.map(
+                    lambda p, gg: (p.astype(jnp.float32) - eta
+                                   * gg.astype(jnp.float32)).astype(p.dtype),
+                    p_loc, g)
             return (p_loc, next_alpha_max(res.alpha, opt.armijo),
                     ev + res.n_evals.astype(jnp.float32)), (loss, res.alpha)
 
@@ -441,9 +445,10 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                     stacked_mask=smask, gamma_t=gamma_t,
                     transport=opt.transport)
             new_overlap = opt_state.overlap
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
-            params, updates)
+        with jax.named_scope("csgd_apply"):
+            new_params = jax.tree.map(
+                lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
+                params, updates)
         cum_eff = opt_state.cum_eff_bytes + jax.lax.pmean(eff_wire, dp)
         metrics = {
             "loss": jax.lax.pmean(jnp.mean(losses), dp),
@@ -463,13 +468,15 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                 * opt_state.overlap.seeded[0], dp)
 
         # ---- step-level circuit breaker (DESIGN.md §16) -----------------
-        health = jax.tree.map(lambda x: x[0], opt_state.health)
-        step_ok = jnp.isfinite(metrics["loss"]) & all_finite(updates)
-        if breaker_on:
-            new_params = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_params, params)
-        new_health = advance_health(health, step_ok, opt_state.step,
-                                    tel.rows_quarantined)
+        with jax.named_scope("csgd_apply"):
+            health = jax.tree.map(lambda x: x[0], opt_state.health)
+            step_ok = jnp.isfinite(metrics["loss"]) & all_finite(updates)
+            if breaker_on:
+                new_params = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_params,
+                    params)
+            new_health = advance_health(health, step_ok, opt_state.step,
+                                        tel.rows_quarantined)
         metrics["steps_skipped"] = \
             new_health.steps_skipped.astype(jnp.float32)
         metrics["consecutive_skips"] = \
@@ -497,8 +504,9 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                 gamma=opt_state.gamma,
                 telemetry=opt_state.telemetry,
                 overlap=opt_state.overlap)
-            new_state = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
+            with jax.named_scope("csgd_apply"):
+                new_state = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
         return new_params, new_state, metrics
 
     def _federated_worker(params, opt_state, batch):
@@ -521,9 +529,10 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
             return jax.lax.psum(jnp.sum(pl * x_c), dp) / n_part
 
         # ---- per-client gradients (ONE vmap over the local cohort) ------
-        losses, grads_c = jax.vmap(
-            lambda mb: jax.value_and_grad(local_loss)(params, mb))(cbatch)
-        gsq_c = jax.vmap(tree_sqnorm)(grads_c)
+        with jax.named_scope("csgd_grad"):
+            losses, grads_c = jax.vmap(
+                lambda mb: jax.value_and_grad(local_loss)(params, mb))(cbatch)
+            gsq_c = jax.vmap(tree_sqnorm)(grads_c)
         metrics = {"loss": wmean(losses), "grad_sqnorm": wmean(gsq_c),
                    "participants": jnp.sum(mask.astype(jnp.float32))}
 
@@ -545,11 +554,12 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
         # ---- per-client step sizes --------------------------------------
         if opt.kind == "csgd_asss":
             amax_c = next_alpha_max(fedst.alpha, opt.armijo)
-            res = jax.vmap(
-                lambda mb, g, f0, gsq, amax: armijo_search(
-                    lambda p: local_loss(p, mb), params, g, amax,
-                    opt.armijo, f0=f0, grad_sqnorm=gsq))(
-                cbatch, grads_c, losses, gsq_c, amax_c)
+            with jax.named_scope("csgd_armijo"):
+                res = jax.vmap(
+                    lambda mb, g, f0, gsq, amax: armijo_search(
+                        lambda p: local_loss(p, mb), params, g, amax,
+                        opt.armijo, f0=f0, grad_sqnorm=gsq))(
+                    cbatch, grads_c, losses, gsq_c, amax_c)
             alpha_c = res.alpha
             evals_c = res.n_evals.astype(jnp.float32)
             eta_c = jax.vmap(
@@ -578,17 +588,20 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                     grads_c, fedst.memory, eta_c, opt.compressor, dp, mask,
                     gamma_used, stacked_mask=smask,
                     aggregation=fed.aggregation, return_quarantined=True)
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
-            params, updates)
-
-        # ---- step-level circuit breaker (DESIGN.md §16) -----------------
-        health = jax.tree.map(lambda x: x[0], opt_state.health)
-        step_ok = jnp.isfinite(metrics["loss"]) & all_finite(updates)
-        if breaker_on:
+        with jax.named_scope("csgd_apply"):
             new_params = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_params, params)
-        new_health = advance_health(health, step_ok, opt_state.step, quar)
+                lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
+                params, updates)
+
+            # ---- step-level circuit breaker (DESIGN.md §16) -------------
+            health = jax.tree.map(lambda x: x[0], opt_state.health)
+            step_ok = jnp.isfinite(metrics["loss"]) & all_finite(updates)
+            if breaker_on:
+                new_params = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_params,
+                    params)
+            new_health = advance_health(health, step_ok, opt_state.step,
+                                        quar)
         metrics["steps_skipped"] = \
             new_health.steps_skipped.astype(jnp.float32)
         metrics["consecutive_skips"] = \
@@ -624,8 +637,9 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
         )
         if breaker_on:
             frozen = new_state._replace(fed=opt_state.fed)
-            new_state = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
+            with jax.named_scope("csgd_apply"):
+                new_state = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
         return new_params, new_state, metrics
 
     def worker_fn(params, opt_state, batch):
@@ -655,37 +669,38 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                                        tel_prev)
 
         # ---- gradient over microbatches (accumulated) -------------------
-        if micro > 1:
-            mbs = jax.tree.map(
-                lambda x: x.reshape(micro, x.shape[0] // micro, *x.shape[1:]),
-                batch)
-            probe = jax.tree.map(lambda x: x[0], mbs)
+        with jax.named_scope("csgd_grad"):
+            if micro > 1:
+                mbs = jax.tree.map(
+                    lambda x: x.reshape(micro, x.shape[0] // micro,
+                                        *x.shape[1:]), batch)
+                probe = jax.tree.map(lambda x: x[0], mbs)
 
-            def acc(carry, mb):
-                lo, g = jax.value_and_grad(local_loss)(params, mb)
-                cl, cg = carry
-                return (cl + lo, jax.tree.map(jnp.add, cg, g)), None
+                def acc(carry, mb):
+                    lo, g = jax.value_and_grad(local_loss)(params, mb)
+                    cl, cg = carry
+                    return (cl + lo, jax.tree.map(jnp.add, cg, g)), None
 
-            zero_g = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (loss_sum, grads), _ = jax.lax.scan(
-                acc, (jnp.float32(0.0), zero_g), mbs)
-            loss = loss_sum / micro
-            grads = jax.tree.map(lambda g: g / micro, grads)
-        else:
-            probe = batch
-            loss, grads = jax.value_and_grad(local_loss)(params, batch)
-
-        gsq = tree_sqnorm(grads)
+                zero_g = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                (loss_sum, grads), _ = jax.lax.scan(
+                    acc, (jnp.float32(0.0), zero_g), mbs)
+                loss = loss_sum / micro
+                grads = jax.tree.map(lambda g: g / micro, grads)
+            else:
+                probe = batch
+                loss, grads = jax.value_and_grad(local_loss)(params, batch)
+            gsq = tree_sqnorm(grads)
         metrics = {"loss": jax.lax.pmean(loss, dp),
                    "grad_sqnorm": jax.lax.pmean(gsq, dp)}
 
         # ---- step size --------------------------------------------------
         if opt.kind in ("csgd_asss", "sls"):
             amax = next_alpha_max(alpha_prev, opt.armijo)
-            res = armijo_search(lambda p: local_loss(p, probe), params,
-                                grads, amax, opt.armijo,
-                                grad_sqnorm=gsq)
+            with jax.named_scope("csgd_armijo"):
+                res = armijo_search(lambda p: local_loss(p, probe), params,
+                                    grads, amax, opt.armijo,
+                                    grad_sqnorm=gsq)
             new_alpha = res.alpha
             new_ema = 0.9 * ema + 0.1 * res.n_evals.astype(jnp.float32)
             metrics["alpha"] = jax.lax.pmean(res.alpha, dp)
@@ -808,7 +823,9 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                         send, mem, eta, opt.compressor, dp,
                         stacked_mask=smask, gamma_t=gamma_t,
                         transport=opt.transport)
-            new_mem = jax.tree.map(lambda x: x[None], new_mem)
+            # the EF memory written back in the state's per-worker layout
+            with jax.named_scope("csgd_ef"):
+                new_mem = jax.tree.map(lambda x: x[None], new_mem)
         else:
             updates, wire = dense_aggregate(grads, eta, dp)
             eff_wire = wire
@@ -836,27 +853,29 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
         metrics["ef_backlog"] = jax.lax.pmean(tel.ef_backlog, dp)
         metrics["ef_cosine"] = jax.lax.pmean(tel.cosine, dp)
 
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
-            params, updates)
-
-        # ---- step-level circuit breaker (DESIGN.md §16) -----------------
-        health = jax.tree.map(lambda x: x[0], opt_state.health)
-        step_ok = jnp.isfinite(metrics["loss"])
-        if not gossip_mode:
-            # the decoded aggregate is replicated (every worker decodes
-            # the same gathered payload), so the update check adds no
-            # collective; under gossip updates are per-worker by design
-            # and the breaker couples through the pmean'd loss alone — a
-            # NaN anywhere poisons the mean within one round
-            step_ok &= all_finite(updates)
-        if breaker_on:
+        with jax.named_scope("csgd_apply"):
             new_params = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_params, params)
-        quar_round = tel.rows_quarantined if compressing \
-            else jnp.float32(0.0)
-        new_health = advance_health(health, step_ok, opt_state.step,
-                                    quar_round)
+                lambda p, u: (p.astype(jnp.float32) - u).astype(p.dtype),
+                params, updates)
+
+            # ---- step-level circuit breaker (DESIGN.md §16) -------------
+            health = jax.tree.map(lambda x: x[0], opt_state.health)
+            step_ok = jnp.isfinite(metrics["loss"])
+            if not gossip_mode:
+                # the decoded aggregate is replicated (every worker decodes
+                # the same gathered payload), so the update check adds no
+                # collective; under gossip updates are per-worker by design
+                # and the breaker couples through the pmean'd loss alone —
+                # a NaN anywhere poisons the mean within one round
+                step_ok &= all_finite(updates)
+            if breaker_on:
+                new_params = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_params,
+                    params)
+            quar_round = tel.rows_quarantined if compressing \
+                else jnp.float32(0.0)
+            new_health = advance_health(health, step_ok, opt_state.step,
+                                        quar_round)
         metrics["steps_skipped"] = \
             new_health.steps_skipped.astype(jnp.float32)
         metrics["consecutive_skips"] = \
@@ -920,8 +939,9 @@ def build_train_step(model: Model, run_cfg: RunConfig, mesh):
                 overlap=opt_state.overlap,
                 downlink=opt_state.downlink,
                 velocity=opt_state.velocity)
-            new_state = jax.tree.map(
-                lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
+            with jax.named_scope("csgd_apply"):
+                new_state = jax.tree.map(
+                    lambda a, b: jnp.where(step_ok, a, b), new_state, frozen)
         return new_params, new_state, metrics
 
     # ---- specs ------------------------------------------------------------

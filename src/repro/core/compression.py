@@ -123,9 +123,10 @@ def threshold_select(x: jax.Array, tau: jax.Array) -> jax.Array:
 
 
 def block_extract_sparse(x2d: jax.Array, comp: "Compressor"):
-    """Wire pairs via exact per-block top-k_b — THE block extraction used
-    by every block_topk path (compress_sparse, compress_leaf, and the
-    fused-kernel path in dcsgd).
+    """Wire pairs via exact per-block top-k_b — THE block extraction of
+    the jnp block_topk paths (compress_sparse, compress_leaf).  The fused
+    kernel path takes the same pairs from its pass-1 kernel
+    (``kernels.ops.EFSplit``), bit-identical to this applied to ``sent``.
 
     x2d: (L, d) per-layer rows; blocks never span layers.  Returns
     (vals, idx), each (L, nb*k_b), idx flat into [0, d) (clamped — padding

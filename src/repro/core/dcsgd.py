@@ -46,7 +46,7 @@ from repro.comm.exchange import (check_bucket_payload, check_payload,
                                  gather_packed)
 from repro.comm.transport import get_transport, register_transport
 from repro.kernels import ops
-from .compression import Compressor, block_extract_sparse
+from .compression import Compressor
 from .leafmath import compress_leaf, select_and_encode
 # the leaf math lives in repro.core.leafmath (shared with the gossip
 # transport); the historical underscore names stay importable from here
@@ -273,18 +273,18 @@ def _perleaf_exchange(flat_g, flat_m, flat_s, eta, comp, dp_axes, gamma_t,
                 # (sent, m') — the accumulator never round-trips through HBM.
                 m2 = _leaf_2d(m, stacked).astype(jnp.float32)
                 # threshold at the BUDGET level (geometry_gamma == max_gamma
-                # for adaptive compressors): block_extract_sparse below pulls
-                # exactly block_k() budget entries per block, and any
-                # per-round k_t mask is applied at encode time
-                sent, resid, _, moments = ops.fused_ef_compress(
+                # for adaptive compressors): pass 1 hands out exactly
+                # block_k() budget pairs per block, in block top-k order
+                # (ties at tau past k_b stay in sent, off the wire, and are
+                # recycled into m' below); any per-round k_t mask is
+                # applied at encode time
+                out = ops.fused_ef_compress(
                     m2, g2f, eta, comp.geometry_gamma, comp.block,
                     telemetry=True)
-                leaf_g_sq = jnp.sum(moments[:, 0])
-                leaf_acc_sq = jnp.sum(moments[:, 1])
-                # per-block top-k_b of |sent| recovers the kept wire entries
-                # (>= k_b survive the threshold; ties beyond k_b are dropped
-                # from the wire and recycled into m' below)
-                vals, idx = block_extract_sparse(sent, comp)
+                sent, resid, vals, idx = out.sent, out.resid, out.vals, \
+                    out.idx
+                leaf_g_sq = jnp.sum(out.moments[:, 0])
+                leaf_acc_sq = jnp.sum(out.moments[:, 1])
             else:
                 acc2 = _leaf_2d(m, stacked).astype(jnp.float32) + eta * g2f
                 leaf_g_sq = jnp.sum(g2f * g2f)

@@ -128,28 +128,35 @@ def select_and_encode(flat_g, flat_m, flat_s, eta, comp: Compressor,
         n = len(lanes)
         comp_ids = list(plan.compressed_ids)
         sel = Selection(use_fused, *([None] * n for _ in range(8)))
+        pairs = {}
         if use_fused and comp_ids:
             ms = [leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32)
                   for i in comp_ids]
             gs = [leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
                   for i in comp_ids]
             # one pass-1 + one pass-2 launch for ALL leaves; thresholds stay
-            # at the BUDGET level exactly as in the per-leaf path
+            # at the BUDGET level exactly as in the per-leaf path.  Pass 1
+            # ranks each block and hands out its k_b wire pairs: the same
+            # entries and order as a block top-k_b of |sent| (ties past k_b
+            # stay in sent, off the wire, and land in m' through
+            # sent - own_dense), so no sort or gather runs here
             outs = ops.fused_ef_compress_batched(
                 ms, gs, eta, comp.geometry_gamma, comp.block, telemetry=True)
-            for i, g2, (s, r, _, moments) in zip(comp_ids, gs, outs):
-                sel.g2f[i], sel.sent[i], sel.resid[i] = g2, s, r
+            for i, g2, out in zip(comp_ids, gs, outs):
+                sel.g2f[i], sel.sent[i], sel.resid[i] = g2, out.sent, \
+                    out.resid
+                pairs[i] = out.vals, out.idx
                 # NB: the batched kernel's per-leaf outputs are bit-identical
                 # to per-leaf launches, but THIS reduce may fuse differently
                 # in the two programs — XLA does not pin f32 reduction order
                 # across program shapes, so telemetry parity is a few-ulp
                 # contract while every other output is bit-exact (DESIGN §11)
-                sel.leaf_g_sq[i] = jnp.sum(moments[:, 0])
-                sel.leaf_acc_sq[i] = jnp.sum(moments[:, 1])
+                sel.leaf_g_sq[i] = jnp.sum(out.moments[:, 0])
+                sel.leaf_acc_sq[i] = jnp.sum(out.moments[:, 1])
         for i in comp_ids:
             lane = lanes[i]
             if use_fused:
-                vals, idx = block_extract_sparse(sel.sent[i], comp)
+                vals, idx = pairs[i]
             else:
                 g2 = leaf_2d(flat_g[i], flat_s[i]).astype(jnp.float32)
                 a2 = leaf_2d(flat_m[i], flat_s[i]).astype(jnp.float32) \

@@ -11,17 +11,23 @@ A naive jnp composition reads ``acc`` three times from HBM and materializes
 intermediates; the fused kernel streams each element exactly once:
 2 reads + 2 writes, perfectly memory-bound at 4 bytes/elem/stream.
 
-Three kernels implement the two-pass block-local scheme (DESIGN.md §3):
+The kernels implement the two-pass block-local scheme (DESIGN.md §3):
 
-* pass 1 ``_block_stats_kernel``  — per-block k_b-th largest |acc|,
-  computing ``acc = m + eta*g`` on the fly (2 reads, tiny write);
+* pass 1 ``_ef_block_stats_kernel`` / ``_ef_stats_telemetry_kernel`` —
+  ranks each block of ``acc = m + eta*g`` (formed on the fly: 2 reads)
+  and writes its k_b-th largest |acc| AND the block's k_b wire pairs, the
+  block-local lanes and signed values of its top-k_b entries in
+  ``lax.top_k`` order, so no later sort or gather is needed;
 * pass 2 ``_ef_apply_kernel``     — the fused elementwise update above,
   thresholding each 1024-wide block against ITS OWN tau from pass 1;
-* ``_threshold_split_kernel``     — single-input variant (x -> sent,
-  residual) for the dense ``Compressor.compress_dense`` path.
+* ``_block_stats_kernel``         — the single-input k_b-th |x| statistic
+  and ``_threshold_split_kernel`` (x -> sent, residual) for the dense
+  ``Compressor.compress_dense`` path.
 
 Blocks are (8, 128)-lane aligned for the VPU; tensors are processed as
-(rows, 1024) tiles resident in VMEM.
+(rows, 1024) tiles resident in VMEM.  Pass 1 writes its pairs transposed,
+(k_b, rows) with the block rows on lanes, so the pair outputs are dense
+in HBM instead of padding k_b lanes out to 128.
 """
 from __future__ import annotations
 
@@ -39,7 +45,7 @@ ROWS = 256
 COLS = 1024
 
 
-# Interpret-mode row cap: the `_kth_largest` fori_loop re-touches its
+# Interpret-mode row cap: the `_rank_blocks` fori_loop re-touches its
 # whole tile every iteration, so off-TPU the tile should sit in L2 —
 # (64, 512) f32 = 128 KiB was the measured optimum on the bucketed
 # transport's concatenated row counts (~30% faster than 256-row tiles).
@@ -64,30 +70,66 @@ def _tile_rows(R: int, interpret: bool) -> int:
     return rows if rows == R else -(-rows // 8) * 8
 
 
-def _kth_largest(mag: jax.Array, k_b: int) -> jax.Array:
-    """k_b-th largest value per row of ``mag`` (rows, C) via iterative
+def _lane_tile_rows(R: int, interpret: bool) -> int:
+    """Row-tile height for pass 1, whose pair outputs carry the block rows
+    on lanes: on TPU a tile shorter than R must span whole 128-lane
+    vregs, so :func:`_tile_rows`' height is rounded up to a multiple of
+    128 (the last tile runs past R; every op is row-local)."""
+    rows = _tile_rows(R, interpret)
+    return rows if interpret or rows == R else -(-rows // 128) * 128
+
+
+def _rank_blocks(x: jax.Array, k_b: int, *, pairs: bool):
+    """Rank each row of ``x`` (rows, C) by |x| via iterative
     max-extraction — k_b is small (= gamma*block <= ~32), so this maps to
     VPU max-reductions rather than a full sort; the MXU stays free.
 
     Exactly ONE element is knocked out per iteration (ties broken by
     lowest lane index), so duplicated magnitudes count like lax.top_k's
     and the result matches the ref.py oracle bit-for-bit.
+
+    Returns the k_b-th largest |x| per row, (rows, 1).  With ``pairs``
+    also the k_b entries knocked out, in knock-out order — exactly
+    ``lax.top_k(|x|, k_b)``'s indices and order — as block-local lanes
+    (k_b, rows) int32 and signed values (k_b, rows) f32.  The search key
+    is ``2*lane + signbit(x)``: keys are distinct per lane, so the min
+    over tied keys is the lowest tied lane and carries its sign, and a
+    value is its magnitude with that sign (-0.0 included) at no extra
+    reduction.
     """
-    rows, C = mag.shape
+    rows, C = x.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, C), 1)
+    if pairs:
+        sign = jax.lax.shift_right_logical(
+            jax.lax.bitcast_convert_type(x, jnp.int32), 31)
+        key, miss = 2 * lane + sign, 2 * C
+        width = -(-k_b // 128) * 128                # whole vregs of slots
+        slot = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    else:
+        key, miss = lane, C
 
     def body(i, carry):
-        mag_c, cur = carry
+        mag_c, cur = carry[:2]
         cur = jnp.max(mag_c, axis=-1, keepdims=True)      # (rows, 1)
-        hit = jnp.min(jnp.where(mag_c >= cur, lane, C),
+        hit = jnp.min(jnp.where(mag_c >= cur, key, miss),
                       axis=-1, keepdims=True)             # first argmax
-        mag_c = jnp.where(lane == hit, -jnp.inf, mag_c)
-        return (mag_c, cur)
+        mag_c = jnp.where(key == hit, -jnp.inf, mag_c)
+        if not pairs:
+            return mag_c, cur
+        keys, mags = carry[2:]
+        return (mag_c, cur, jnp.where(slot == i, hit, keys),
+                jnp.where(slot == i, cur, mags))
 
-    _, kth = jax.lax.fori_loop(0, k_b, body,
-                               (mag, jnp.zeros((mag.shape[0], 1),
-                                               jnp.float32)))
-    return kth
+    init = (jnp.abs(x), jnp.zeros((rows, 1), jnp.float32))
+    if pairs:
+        init += (jnp.zeros((rows, width), jnp.int32),
+                 jnp.zeros((rows, width), jnp.float32))
+    out = jax.lax.fori_loop(0, k_b, body, init)
+    if not pairs:
+        return out[1]
+    _, kth, keys, mags = out
+    vals = jnp.where((keys & 1) == 1, -mags, mags)
+    return kth, (keys >> 1).T[:k_b], vals.T[:k_b]
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +186,8 @@ def _block_stats_kernel(x_ref, out_ref, *, k_b: int):
 
     x_ref: (rows, C) tile; out_ref: (rows, 1) thresholds per row-block.
     """
-    mag = jnp.abs(x_ref[...].astype(jnp.float32))
-    out_ref[...] = _kth_largest(mag, k_b)
+    out_ref[...] = _rank_blocks(x_ref[...].astype(jnp.float32), k_b,
+                                pairs=False)
 
 
 @functools.partial(jax.jit, static_argnames=("k_b", "interpret"))
@@ -164,48 +206,74 @@ def block_stats(x: jax.Array, k_b: int, *, interpret: bool = True):
     )(x)
 
 
-def _ef_block_stats_kernel(m_ref, g_ref, eta_ref, out_ref, *, k_b: int):
-    """Fused pass 1: per-block k_b-th largest |m + eta*g| — the accumulator
-    is formed on the fly so it is never written back to HBM."""
+def _ef_block_stats_kernel(m_ref, g_ref, eta_ref, tau_ref, val_ref, idx_ref,
+                           *, k_b: int):
+    """Fused pass 1: per-block k_b-th largest |m + eta*g| and the block's
+    k_b wire pairs — the accumulator is formed on the fly so it is never
+    written back to HBM.
+
+    tau_ref: (rows, 1); val_ref, idx_ref: (k_b, rows) signed values and
+    block-local lanes of each block row's top-k_b, in lax.top_k order.
+    """
     eta = eta_ref[0]
     acc = m_ref[...].astype(jnp.float32) + eta * g_ref[...].astype(jnp.float32)
-    out_ref[...] = _kth_largest(jnp.abs(acc), k_b)
+    tau_ref[...], idx_ref[...], val_ref[...] = _rank_blocks(acc, k_b,
+                                                            pairs=True)
 
 
-def _ef_stats_telemetry_kernel(m_ref, g_ref, eta_ref, tau_ref, mom_ref, *,
-                               k_b: int):
+def _ef_stats_telemetry_kernel(m_ref, g_ref, eta_ref, tau_ref, val_ref,
+                               idx_ref, mom_ref, *, k_b: int):
     """Fused pass 1 + telemetry moments (DESIGN.md §10): the same streaming
     pass that ranks |acc| also reduces the two dense telemetry moments —
     ``sum g^2`` and ``sum acc^2`` per block row — while the operands sit in
     VMEM, so the compression-telemetry signal costs no extra HBM sweep.
 
-    mom_ref: (rows, 2) per block row: [sum g^2, sum acc^2].
+    mom_ref: (rows, 2) per block row: [sum g^2, sum acc^2]; the other
+    outputs as in :func:`_ef_block_stats_kernel`.
     """
     eta = eta_ref[0]
     gf = g_ref[...].astype(jnp.float32)
     acc = m_ref[...].astype(jnp.float32) + eta * gf
-    tau_ref[...] = _kth_largest(jnp.abs(acc), k_b)
+    tau_ref[...], idx_ref[...], val_ref[...] = _rank_blocks(acc, k_b,
+                                                            pairs=True)
     mom_ref[...] = jnp.concatenate(
         [jnp.sum(gf * gf, axis=-1, keepdims=True),
          jnp.sum(acc * acc, axis=-1, keepdims=True)], axis=-1)
 
 
+def _pass1(kernel, m, g, eta, k_b, n_moments, interpret):
+    nb, C = m.shape
+    rows = _lane_tile_rows(nb, interpret)
+    row_spec = lambda w: pl.BlockSpec((rows, w), lambda i: (i, 0))
+    pair_spec = pl.BlockSpec((k_b, rows), lambda i: (0, i))
+    out_specs = [row_spec(1), pair_spec, pair_spec]
+    out_shape = [jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((k_b, nb), jnp.float32),
+                 jax.ShapeDtypeStruct((k_b, nb), jnp.int32)]
+    if n_moments:
+        out_specs.append(row_spec(n_moments))
+        out_shape.append(jax.ShapeDtypeStruct((nb, n_moments), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(kernel, k_b=k_b),
+        grid=(pl.cdiv(nb, rows),),
+        in_specs=[row_spec(C), row_spec(C),
+                  pl.BlockSpec((1,), lambda i: (0,))],
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shape),
+        interpret=interpret,
+    )(m, g, eta.reshape(1))
+
+
 @functools.partial(jax.jit, static_argnames=("k_b", "interpret"))
 def ef_block_stats(m: jax.Array, g: jax.Array, eta: jax.Array, k_b: int,
                    *, interpret: bool = True):
-    """Per-block k_b-th largest |m + eta*g|. m, g: (nb, C) -> (nb, 1) f32."""
-    nb, C = m.shape
-    rows = _tile_rows(nb, interpret)
-    grid = (pl.cdiv(nb, rows),)
-    spec = pl.BlockSpec((rows, C), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_ef_block_stats_kernel, k_b=k_b),
-        grid=grid,
-        in_specs=[spec, spec, pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-        interpret=interpret,
-    )(m, g, eta.reshape(1))
+    """Fused pass 1 over (nb, C) block rows of m, g.
+
+    Returns (tau: (nb, 1) f32, vals: (k_b, nb) f32, lanes: (k_b, nb)
+    int32): per block row the k_b-th largest |m + eta*g| and its top-k_b
+    entries' signed values and block-local lanes, in lax.top_k order.
+    """
+    return _pass1(_ef_block_stats_kernel, m, g, eta, k_b, 0, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k_b", "interpret"))
@@ -213,24 +281,10 @@ def ef_stats_telemetry(m: jax.Array, g: jax.Array, eta: jax.Array, k_b: int,
                        *, interpret: bool = True):
     """Fused pass 1 with telemetry moments.  m, g: (nb, C).
 
-    Returns (tau: (nb, 1) f32, moments: (nb, 2) f32 = [sum g^2, sum acc^2]
-    per block row).
+    Returns :func:`ef_block_stats`' (tau, vals, lanes) and moments:
+    (nb, 2) f32 = [sum g^2, sum acc^2] per block row.
     """
-    nb, C = m.shape
-    rows = _tile_rows(nb, interpret)
-    grid = (pl.cdiv(nb, rows),)
-    spec = pl.BlockSpec((rows, C), lambda i: (i, 0))
-    out_shape = (jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-                 jax.ShapeDtypeStruct((nb, 2), jnp.float32))
-    return pl.pallas_call(
-        functools.partial(_ef_stats_telemetry_kernel, k_b=k_b),
-        grid=grid,
-        in_specs=[spec, spec, pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=(pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((rows, 2), lambda i: (i, 0))),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(m, g, eta.reshape(1))
+    return _pass1(_ef_stats_telemetry_kernel, m, g, eta, k_b, 2, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -181,62 +181,74 @@ def block_topk_threshold(x, k_b: int, block: int = 1024, *,
     return dispatch.call("block_stats", x2, k_b, impl=impl).reshape(-1)
 
 
-def ef_block_stats(m, g, eta, k_b: int, block: int = 1024, *,
-                   impl: str | None = None):
-    """Fused pass 1: per-block k_b-th largest |m + eta*g|; (L*nb, 1) f32.
+class EFSplit(NamedTuple):
+    """One leaf's fused EF compression (DESIGN.md §3).
 
-    m, g: (d,) or (L, d); blocks never span layers.
+    ``sent``/``resid`` have m's shape with ``sent + resid == m + eta*g``
+    exactly; ``tau`` is (L*nb, 1); ``moments`` ((L*nb, 2) f32, [sum g^2,
+    sum acc^2] per block row) is None without telemetry.
+
+    ``vals``/``idx`` are the wire pairs pass 1 ranked, each (L, nb*k_b):
+    per layer row, block by block, the block's top-k_b of |acc| in
+    lax.top_k order — values f32, indices int32 flat into [0, d) with
+    padding lanes clamped to d - 1 — the layout of
+    ``compression.block_extract_sparse``.  For f32 operands they equal
+    ``block_extract_sparse(sent)`` bit for bit: ``sent`` is ``acc`` on
+    every entry at or above its block's tau and 0 elsewhere, and both
+    rankings break ties by the lowest lane.  Entries tied at tau past the
+    k_b-th stay in ``sent`` but off the wire.
     """
-    m2, _ = _to_blocks(m, block)
-    g2, _ = _to_blocks(g, block)
-    return dispatch.call("ef_stats", m2, g2, jnp.asarray(eta, jnp.float32),
-                         k_b, impl=impl)
+
+    sent: jax.Array
+    resid: jax.Array
+    tau: jax.Array
+    vals: jax.Array
+    idx: jax.Array
+    moments: jax.Array | None = None
+
+
+def _leaf_pairs(vals, lanes, meta, block: int):
+    """A leaf's (k_b, L*nb) pass-1 pairs -> (L, nb*k_b) wire rows."""
+    _, L, d = meta
+    k_b = vals.shape[0]
+    lanes = lanes.T.reshape(L, -1, k_b)
+    base = jnp.arange(lanes.shape[1], dtype=jnp.int32)[None, :, None] * block
+    idx = jnp.minimum((lanes + base).reshape(L, -1), d - 1)
+    return vals.T.reshape(L, -1), idx
 
 
 def fused_ef_compress(m, g, eta, gamma: float, block: int = 1024, *,
-                      telemetry: bool = False, impl: str | None = None):
-    """The full two-pass fused EF compression (DESIGN.md §3).
+                      telemetry: bool = False,
+                      impl: str | None = None) -> EFSplit:
+    """The full two-pass fused EF compression (DESIGN.md §3) of one leaf.
 
     Per 1024-wide block b of ``acc = m + eta*g`` (blocks never span the
-    leading layer axis): tau_b = k_b-th largest |acc_b| with
-    k_b = round(gamma*block); sent keeps entries with |acc| >= tau_b and
-    m' carries the rest.  Returns (sent, m', tau) where sent/m' have m's
-    shape and ``sent + m' == m + eta*g`` holds exactly; tau is (L*nb, 1).
+    leading layer axis of an (L, d) leaf): tau_b = k_b-th largest |acc_b|
+    with k_b = round(gamma*block); sent keeps entries with |acc| >= tau_b
+    and m' carries the rest; pass 1 also hands out the wire pairs
+    (:class:`EFSplit`).
 
     ``telemetry`` (DESIGN.md §10): pass 1 additionally reduces the dense
     telemetry moments [sum g^2, sum acc^2] per block row on the same
-    streamed operands — no extra HBM sweep — and a fourth element
-    ``moments`` ((L*nb, 2) f32) is returned.
+    streamed operands — no extra HBM sweep.
     """
-    k_b = max(1, int(round(gamma * block)))
-    m2, meta = _to_blocks(m, block)
-    g2, _ = _to_blocks(g, block)
-    eta = jnp.asarray(eta, jnp.float32)
-    if telemetry:
-        tau, moments = dispatch.call("ef_stats_telemetry", m2, g2, eta, k_b,
-                                     impl=impl)
-    else:
-        tau = dispatch.call("ef_stats", m2, g2, eta, k_b, impl=impl)
-    sent, mnew = dispatch.call("ef_update", m2, g2, eta, tau, impl=impl)
-    if telemetry:
-        return _from_blocks(sent, meta), _from_blocks(mnew, meta), tau, \
-            moments
-    return _from_blocks(sent, meta), _from_blocks(mnew, meta), tau
+    return fused_ef_compress_batched([m], [g], eta, gamma, block,
+                                     telemetry=telemetry, impl=impl)[0]
 
 
 def fused_ef_compress_batched(ms, gs, eta, gamma: float, block: int = 1024,
                               *, telemetry: bool = False,
-                              impl: str | None = None):
+                              impl: str | None = None) -> list[EFSplit]:
     """Batched :func:`fused_ef_compress` over a LIST of (L_i, d_i) leaf
     pairs — ONE pass-1 launch and ONE pass-2 launch for the whole list
     (bucket-shaped launches, DESIGN.md §11).
 
     Every op in the two-pass scheme is block-row-local (blocks never span
-    rows, thresholds/moments are per block row, the EF update is
+    rows, thresholds/moments/pairs are per block row, the EF update is
     elementwise against its row's tau), so concatenating all leaves' block
-    rows changes the launch geometry and nothing else: the returned list
-    of per-leaf ``(sent, m', tau[, moments])`` tuples is bit-identical to
-    per-leaf :func:`fused_ef_compress` calls.
+    rows changes the launch geometry and nothing else: the returned
+    per-leaf :class:`EFSplit` list is bit-identical to per-leaf
+    :func:`fused_ef_compress` calls.
     """
     k_b = max(1, int(round(gamma * block)))
     blocks_m, blocks_g, metas, offs = [], [], [], [0]
@@ -250,21 +262,23 @@ def fused_ef_compress_batched(ms, gs, eta, gamma: float, block: int = 1024,
     cat_m = jnp.concatenate(blocks_m, axis=0)
     cat_g = jnp.concatenate(blocks_g, axis=0)
     eta = jnp.asarray(eta, jnp.float32)
+    moments = None
     if telemetry:
-        tau, moments = dispatch.call("ef_stats_telemetry", cat_m, cat_g,
-                                     eta, k_b, impl=impl)
+        tau, vals, lanes, moments = dispatch.call(
+            "ef_stats_telemetry", cat_m, cat_g, eta, k_b, impl=impl)
     else:
-        tau = dispatch.call("ef_stats", cat_m, cat_g, eta, k_b, impl=impl)
+        tau, vals, lanes = dispatch.call("ef_stats", cat_m, cat_g, eta, k_b,
+                                         impl=impl)
     sent, mnew = dispatch.call("ef_update", cat_m, cat_g, eta, tau,
                                impl=impl)
     out = []
     for i, meta in enumerate(metas):
         rows = slice(offs[i], offs[i + 1])
-        leaf = (_from_blocks(sent[rows], meta),
-                _from_blocks(mnew[rows], meta), tau[rows])
-        if telemetry:
-            leaf = leaf + (moments[rows],)
-        out.append(leaf)
+        out.append(EFSplit(
+            _from_blocks(sent[rows], meta), _from_blocks(mnew[rows], meta),
+            tau[rows], *_leaf_pairs(vals[:, rows], lanes[:, rows], meta,
+                                    block),
+            None if moments is None else moments[rows]))
     return out
 
 
@@ -272,8 +286,8 @@ def threshold_split_blocks(x, tau, block: int = 1024, *,
                            impl: str | None = None):
     """Dense split of x into (sent, residual) against per-block tau.
 
-    x: (d,) or (L, d); tau: (L*nb, 1) from :func:`ef_block_stats` /
-    :func:`block_topk_threshold`.  ``sent + residual == x`` exactly.
+    x: (d,) or (L, d); tau: (L*nb, 1) from :func:`block_topk_threshold`.
+    ``sent + residual == x`` exactly.
     """
     x2, meta = _to_blocks(x, block)
     sent, res = dispatch.call("threshold_split", x2, tau, impl=impl)

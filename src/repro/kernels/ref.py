@@ -48,27 +48,36 @@ def ef_block_update(m: jax.Array, g: jax.Array, eta: jax.Array,
     return sent.astype(m.dtype), (acc - sent).astype(m.dtype)
 
 
+def _block_topk_pairs(acc: jax.Array, k_b: int):
+    """Per-block-row top-k_b of |acc|: the k_b-th magnitude (R, 1) and the
+    wire pairs in the kernel's transposed layout — signed values (k_b, R)
+    f32 and block-local lanes (k_b, R) int32, in lax.top_k order."""
+    mags, lanes = jax.lax.top_k(jnp.abs(acc), k_b)
+    vals = jnp.take_along_axis(acc, lanes, axis=1)
+    return mags[:, -1:], vals.T, lanes.astype(jnp.int32).T
+
+
 def ef_block_stats(m: jax.Array, g: jax.Array, eta: jax.Array,
-                   k_b: int) -> jax.Array:
-    """Per-block-row k_b-th largest |m + eta*g|. (R, C) -> (R, 1) f32."""
+                   k_b: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused pass 1 (DESIGN.md §3) over (R, C) block rows: per row the
+    k_b-th largest |m + eta*g| (R, 1) and its top-k_b wire pairs, signed
+    values (k_b, R) f32 and block-local lanes (k_b, R) int32."""
     acc = m.astype(jnp.float32) + eta.astype(jnp.float32) * g.astype(jnp.float32)
-    vals, _ = jax.lax.top_k(jnp.abs(acc), k_b)
-    return vals[:, -1:]
+    return _block_topk_pairs(acc, k_b)
 
 
 def ef_block_stats_telemetry(m: jax.Array, g: jax.Array, eta: jax.Array,
-                             k_b: int) -> tuple[jax.Array, jax.Array]:
-    """Fused pass 1 + telemetry moments (DESIGN.md §10): per-block-row
-    k_b-th largest |m + eta*g| AND the dense telemetry moments of the same
-    streamed operands.  (R, C) -> (tau (R, 1), moments (R, 2) f32 with
-    columns [sum g^2, sum acc^2])."""
+                             k_b: int):
+    """Fused pass 1 + telemetry moments (DESIGN.md §10):
+    :func:`ef_block_stats`' (tau, vals, lanes) AND the dense telemetry
+    moments of the same streamed operands, (R, 2) f32 with columns
+    [sum g^2, sum acc^2]."""
     gf = g.astype(jnp.float32)
     acc = m.astype(jnp.float32) + eta.astype(jnp.float32) * gf
-    vals, _ = jax.lax.top_k(jnp.abs(acc), k_b)
     moments = jnp.concatenate(
         [jnp.sum(gf * gf, axis=-1, keepdims=True),
          jnp.sum(acc * acc, axis=-1, keepdims=True)], axis=-1)
-    return vals[:, -1:], moments
+    return _block_topk_pairs(acc, k_b) + (moments,)
 
 
 def threshold_split(x: jax.Array, tau: jax.Array) -> tuple[jax.Array,

@@ -60,16 +60,16 @@ def test_fused_ef_identity_bitlevel(key, shape):
     eta = 0.5
     m = jax.random.normal(key, shape, jnp.float32)
     g = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
-    sent, mnew, tau = ops.fused_ef_compress(m, g, eta, gamma=0.03,
-                                            impl="pallas")
+    sent, mnew, tau, *_ = ops.fused_ef_compress(m, g, eta, gamma=0.03,
+                                                impl="pallas")
     acc = np.asarray(m, np.float32) + np.float32(eta) * np.asarray(
         g, np.float32)
     np.testing.assert_array_equal(np.asarray(sent) + np.asarray(mnew), acc)
     # disjoint support: fused split never duplicates or drops a position
     assert not np.any(np.logical_and(np.asarray(sent) != 0,
                                      np.asarray(mnew) != 0))
-    sent_r, mnew_r, tau_r = ops.fused_ef_compress(m, g, eta, gamma=0.03,
-                                                  impl="ref")
+    sent_r, mnew_r, tau_r, *_ = ops.fused_ef_compress(m, g, eta, gamma=0.03,
+                                                      impl="ref")
     np.testing.assert_array_equal(np.asarray(sent), np.asarray(sent_r))
     np.testing.assert_array_equal(np.asarray(mnew), np.asarray(mnew_r))
     np.testing.assert_array_equal(np.asarray(tau), np.asarray(tau_r))
@@ -84,10 +84,10 @@ def test_fused_ef_telemetry_parity(key, shape):
     eta = 0.5                       # power of two: acc exact in numpy too
     m = jax.random.normal(key, shape, jnp.float32)
     g = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
-    s_t, m_t, tau_t, mom_p = ops.fused_ef_compress(
+    s_t, m_t, tau_t, *_, mom_p = ops.fused_ef_compress(
         m, g, eta, gamma=0.03, telemetry=True, impl="pallas")
-    s_p, m_p, tau_p = ops.fused_ef_compress(m, g, eta, gamma=0.03,
-                                            impl="pallas")
+    s_p, m_p, tau_p, *_ = ops.fused_ef_compress(m, g, eta, gamma=0.03,
+                                                impl="pallas")
     np.testing.assert_array_equal(np.asarray(tau_t), np.asarray(tau_p))
     np.testing.assert_array_equal(np.asarray(s_t), np.asarray(s_p))
     np.testing.assert_array_equal(np.asarray(m_t), np.asarray(m_p))
@@ -109,10 +109,107 @@ def test_fused_ef_compress_block_budget(key):
     m = jax.random.normal(key, (4096,))
     g = jax.random.normal(jax.random.fold_in(key, 1), (4096,))
     gamma = 0.05
-    sent, mnew, _ = ops.fused_ef_compress(m, g, 0.2, gamma=gamma)
+    sent, *_ = ops.fused_ef_compress(m, g, 0.2, gamma=gamma)
     k_b = round(gamma * 1024)
     per_block = np.count_nonzero(np.asarray(sent).reshape(4, 1024), axis=1)
     np.testing.assert_array_equal(per_block, np.full(4, k_b))
+
+
+def _pair_case(key, case):
+    """(m, g) for the pass-1 wire-pair parity cases; eta = 0.5 keeps
+    ``m + eta*g`` exact, so numpy can state the expected split."""
+    # padded/stacked: the last block holds fewer than k_b = 10 entries, so
+    # its padding lanes go on the wire, clamped to d - 1
+    shape = {"stacked": (3, 1030), "padded": (2053,)}.get(case, (2, 2048))
+    m = jax.random.normal(key, shape, jnp.float32)
+    g = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
+    if case == "ties":              # a few levels: many lanes share tau
+        m, g = jnp.round(m), jnp.round(g)
+    elif case == "zero_blocks":     # an all-zero block, signed zeros in
+        # it, and a block with fewer nonzeros than k_b (tau = 0)
+        zero = jnp.zeros((1024,), jnp.float32)
+        neg = zero.at[::3].set(-0.0)
+        sparse = zero.at[jnp.array([5, 700, 1000])].set(
+            jnp.array([1.5, -2.0, 0.25]))
+        m = jnp.stack([neg, sparse])
+        g = jnp.stack([neg, zero])
+    return m, g
+
+
+PAIR_CASES = ["normal", "ties", "zero_blocks", "padded", "stacked"]
+
+
+@pytest.mark.parametrize("case", PAIR_CASES)
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("impl", ["ref", "pallas-interpret"])
+def test_pass1_wire_pairs_match_block_extract(key, impl, batched, case):
+    """Pass 1 hands out the wire pairs: bit-identical (order, signed
+    zeros, clamped padding indices) to the block top-k_b of |sent| that
+    ``block_extract_sparse`` takes, while tau, sent, m' and the moments
+    are the plain two-pass split's."""
+    from repro.core.compression import Compressor, block_extract_sparse
+
+    eta, gamma = 0.5, 0.01
+    m, g = _pair_case(key, case)
+    if batched:                     # the leaf shares its launch
+        other = jax.random.normal(jax.random.fold_in(key, 2), (2, 1100))
+        out = ops.fused_ef_compress_batched(
+            [other, m], [other, g], eta, gamma, telemetry=True,
+            impl=impl)[1]
+    else:
+        out = ops.fused_ef_compress(m, g, eta, gamma, telemetry=True,
+                                    impl=impl)
+    comp = Compressor(gamma=gamma, method="block_topk")
+    m2 = np.asarray(m).reshape(-1, m.shape[-1])
+    L, d = m2.shape
+    vals, idx = block_extract_sparse(out.sent.reshape(L, d), comp)
+    np.testing.assert_array_equal(np.asarray(out.vals).view(np.int32),
+                                  np.asarray(vals).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(out.idx), np.asarray(idx))
+    assert out.vals.dtype == jnp.float32 and out.idx.dtype == jnp.int32
+
+    # the split itself, stated in numpy
+    acc = m2 + np.float32(eta) * np.asarray(g).reshape(L, d)
+    nb = -(-d // 1024)
+    blocks = np.pad(acc, ((0, 0), (0, nb * 1024 - d))).reshape(L * nb, 1024)
+    k_b = comp.block_k()
+    tau = -np.sort(-np.abs(blocks), axis=1)[:, k_b - 1:k_b]
+    keep = np.abs(blocks) >= tau
+    sent = np.where(keep, blocks, np.float32(0.0))
+    unblock = lambda x: x.reshape(L, -1)[:, :d].reshape(m.shape)
+    np.testing.assert_array_equal(np.asarray(out.tau), tau)
+    np.testing.assert_array_equal(np.asarray(out.sent).view(np.int32),
+                                  unblock(sent).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(out.resid).view(np.int32),
+                                  unblock(blocks - sent).view(np.int32))
+    g_blocks = np.pad(np.asarray(g, np.float64).reshape(L, d),
+                      ((0, 0), (0, nb * 1024 - d))).reshape(L * nb, 1024)
+    np.testing.assert_allclose(
+        np.asarray(out.moments),
+        np.stack([np.sum(g_blocks ** 2, axis=1),
+                  np.sum(blocks.astype(np.float64) ** 2, axis=1)], axis=1),
+        rtol=1e-6)
+    # without telemetry pass 1 hands out the same pairs
+    plain = ops.fused_ef_compress_batched([m], [g], eta, gamma,
+                                          impl=impl)[0]
+    np.testing.assert_array_equal(np.asarray(plain.vals).view(np.int32),
+                                  np.asarray(out.vals).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(plain.idx),
+                                  np.asarray(out.idx))
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas-interpret"])
+def test_pass1_wire_pairs_non_finite_stay_in_range(key, impl):
+    """Non-finite accumulators need not rank like block_extract_sparse,
+    but every wire index still falls inside the leaf."""
+    m = jax.random.normal(key, (2, 2500), jnp.float32)
+    m = m.at[0, :40].set(jnp.nan).at[1, 1030].set(jnp.inf) \
+        .at[1, 2100:].set(jnp.nan)
+    g = jnp.ones_like(m)
+    out = ops.fused_ef_compress(m, g, 0.5, 0.01, telemetry=True, impl=impl)
+    idx = np.asarray(out.idx)
+    assert idx.shape == (2, 30)
+    assert idx.min() >= 0 and idx.max() < 2500
 
 
 def test_threshold_split_blocks_matches_ref(key):

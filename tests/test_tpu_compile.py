@@ -90,7 +90,11 @@ def test_ef_kernel_compiles(one_chip, kernel, rows):
         "threshold_split": (functools.partial(threshold_split,
                                               interpret=False), (blk, tau)),
     }[kernel]
-    _compile(fn, *shapes, sharding=one_chip)
+    text = _compile(fn, *shapes, sharding=one_chip)
+    if kernel in ("ef_block_stats", "ef_stats_telemetry"):
+        # pass 1's wire pairs come out transposed, block rows on lanes
+        assert f"f32[{K_B},{rows}]" in text
+        assert f"s32[{K_B},{rows}]" in text
 
 
 @pytest.mark.parametrize("words,ragged", [(stream_shape(INDEX_WORDS), False),

@@ -12,6 +12,7 @@ import glob
 import re
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -19,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.configs.base import OptimizerConfig, RunConfig, ShapeConfig
 from repro.core import ArmijoConfig, Compressor
+from repro.core.dcsgd import worker_compress_aggregate
 from repro.kernels import dispatch
 from repro.launch import spans, train
 from repro.launch.mesh import make_mesh
@@ -86,6 +88,64 @@ def test_kernels_fall_under_their_phase(op_names, kernel, phase):
 def test_armijo_loop_falls_under_armijo(op_names):
     loop = [n for n in op_names if re.search(r"csgd_armijo/while\b", n)]
     assert loop
+
+
+def _scoped_eqns(jaxpr, scope: str, stack: str = "") -> list:
+    """(primitive name, first operand shape) of every equation that runs
+    under ``scope``, nested jaxprs (jit, shard_map, loops, Pallas kernel
+    bodies) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if scope in here.split("/"):
+            found.append((eqn.primitive.name,
+                          getattr(eqn.invars[0].aval, "shape", ())
+                          if eqn.invars else ()))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    found += _scoped_eqns(sub, scope, here)
+    return found
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+@pytest.mark.parametrize("transport", ["bucketed", "perleaf"])
+def test_fused_ef_selection_has_no_sort_or_gather(transport, use_kernel):
+    """On the fused path pass 1 hands out the wire pairs, so no top-k or
+    sort runs in ``csgd_ef`` and nothing gathers from the (L, nb, block)
+    block view — in ``select_and_encode`` (the bucketed transport) and in
+    the per-leaf transport's fused branch.  The jnp path
+    (``use_kernel=False``) still ranks with ``lax.top_k`` and gathers the
+    values from the blocks.  (The telemetry's k-entry gather of g at the
+    wire indices runs on both.)"""
+    comp = Compressor(gamma=0.01, method="block_topk", block=1024,
+                      min_compress_size=64, use_kernel=use_kernel)
+    tree = {"w": jnp.zeros((3, 2500)), "v": jnp.zeros((4096,))}
+    mask = {"w": True, "v": False}
+    mesh = make_mesh((1,), ("data",))
+
+    def worker(g, m):
+        return worker_compress_aggregate(g, m, jnp.float32(0.1), comp,
+                                         ("data",), stacked_mask=mask,
+                                         transport=transport)[:2]
+
+    step = jax.shard_map(worker, mesh=mesh, in_specs=(P(), P()),
+                         out_specs=P(), check_vma=False)
+    with dispatch.using("pallas-interpret"):
+        eqns = _scoped_eqns(jax.make_jaxpr(step)(tree, tree).jaxpr,
+                            "csgd_ef")
+    names = {n for n, _ in eqns}
+    ranks = names & {"top_k", "sort"}
+    block_gathers = [s for n, s in eqns
+                     if n == "gather" and len(s) == 3 and s[-1] == 1024]
+    if use_kernel:
+        assert "pallas_call" in names
+        assert not ranks and not block_gathers
+    else:
+        assert "pallas_call" not in names
+        assert ranks == {"top_k"} and len(block_gathers) == 2
 
 
 def test_profile_dir_writes_the_loop_spans(tmp_path):

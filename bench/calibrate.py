@@ -110,11 +110,11 @@ def main(argv=None) -> int:
 
     man = manifest.load()
     entry = manifest.resolve(man, args.workload)
-    config, traffic = entry["config"], entry["traffic"]
+    config, traffic, arch = entry["config"], entry["traffic"], entry["arch"]
     devices = jax.devices()[:entry["cell"]["chips"]]
     harness.env_cache_dir(ROOT)
     n = traffic["check_steps"]
-    tr = harness.Trainer(config, traffic, devices=devices)
+    tr = harness.Trainer(config, traffic, arch, devices=devices)
     faults = {"half_batch": fault.half_batch(tr.workers)}
     out = {"workload": args.workload, "device": devices[0].device_kind,
            "program": [], "control": [], "faults": {k: [] for k in faults}}
@@ -139,7 +139,7 @@ def main(argv=None) -> int:
     jax.clear_caches()
     for i, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        ref = harness.reference_record(config, traffic, seed, n,
+        ref = harness.reference_record(config, traffic, arch, seed, n,
                                        devices=devices)
         prog = progs[seed]
         row = {"seed": seed, **check.numbers(prog, ref),
@@ -157,7 +157,7 @@ def main(argv=None) -> int:
                     {"seed": seed, **check.numbers(runs[seed], ref)})
         if i < args.control:
             ctl = harness.reference_record(
-                config, traffic, seed, n,
+                config, traffic, arch, seed, n,
                 compute=harness.control_compute(config["model"]),
                 devices=devices)
             out["control"].append({"seed": seed, **check.numbers(ctl, ref)})

@@ -5,7 +5,9 @@ It drives the program's own jitted train step
 ``launch/train.py`` builds it, around the program's ``build_model``,
 ``init_opt_state`` / ``opt_state_shardings``, ``param_shardings`` and the
 host-side ``check_divergence`` read.  Weights and tokens come from the
-benchmark (``weights.py``, ``data.py``), made from the seed.
+benchmark (``weights.py``, ``data.py``), made from the seed; the weight
+layout and the reference's loss from the configuration's architecture
+module (``bench/reference/<name>.py``, ``manifest.resolve``'s ``"arch"``).
 
 Set-up compiles the step once and drives that one object, with its state,
 through the cell's first ``check_steps`` steps: the steps the correctness
@@ -23,6 +25,7 @@ import os
 import shutil
 import tempfile
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -94,10 +97,12 @@ def span(name: str, on: bool):
 
 @dataclasses.dataclass
 class Trainer:
-    """The program's train step for one cell, built once."""
+    """The program's train step for one cell, built once.  ``arch`` is
+    the configuration's architecture module."""
 
     config: dict
     traffic: dict
+    arch: types.ModuleType
     devices: list | None = None
 
     def __post_init__(self):
@@ -121,7 +126,7 @@ class Trainer:
         self.rows = t["global_batch"]
         self.seq = t["seq_len"]
         self.tokens_per_step = self.rows * (self.seq - 1)
-        self.abstract = weights.abstract(m)
+        self.abstract = weights.abstract(self.arch, m)
         prog = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         if jax.tree.structure(prog) != jax.tree.structure(self.abstract) \
                 or jax.tree.leaves(prog) != jax.tree.leaves(self.abstract):
@@ -135,7 +140,7 @@ class Trainer:
     # -- state and inputs ---------------------------------------------------
     def fresh_state(self, seed: int):
         with jax.set_mesh(self.mesh):
-            params = weights.make(self.m, seed, self.p_sh)
+            params = weights.make(self.arch, self.m, seed, self.p_sh)
             mask = self.model.stacked_mask(params)
             state = init_opt_state(self.abstract, self.run, self.workers,
                                    abstract=True, stacked_mask=mask)
@@ -215,12 +220,11 @@ def check_steps(tr: Trainer, seed: int, params, state, n: int, feed=None):
     return params, state, rec
 
 
-def reference_record(config: dict, traffic: dict, seed: int, n: int,
+def reference_record(config: dict, traffic: dict, arch, seed: int, n: int,
                      compute: str = "float32", devices=None) -> dict:
-    """The reference's first ``n`` steps from the same weights and tokens.
+    """The reference's first ``n`` steps from the same weights and tokens,
+    with the loss of ``arch``, the configuration's architecture module.
     Workers run on the devices in turn (several chips: in parallel)."""
-    from bench.reference import transformer_lm as lm
-
     m, t = config["model"], traffic
     opt = optimizer_of(t)
     devices = devices or jax.devices()
@@ -230,7 +234,7 @@ def reference_record(config: dict, traffic: dict, seed: int, n: int,
            else contextlib.nullcontext())
 
     def loss_fn(p, tok):
-        return lm.loss(p, tok, m, compute)
+        return arch.loss(p, tok, m, compute)
 
     # a worker's memory is replaced by its new memory: donate it
     step_w = jax.jit(lambda p, mem, a, tok: csgd.worker(loss_fn, opt, p,
@@ -238,10 +242,10 @@ def reference_record(config: dict, traffic: dict, seed: int, n: int,
                      donate_argnums=(1,))
     apply = jax.jit(csgd.apply)
     with ctx:
-        params = weights.make(m, seed)
+        params = weights.make(arch, m, seed)
         p0 = params
         names = csgd.path_names(params)
-        shapes = weights.shapes(m)
+        shapes = weights.shapes(arch, m)
         dev = [devices[w % len(devices)] for w in range(W)]
         mem = [jax.device_put(jax.tree.map(
             lambda x: jnp.zeros(x.shape, jnp.float32), params), d)

@@ -1,7 +1,9 @@
 """``BENCHMARK.json``: load, check against its schema, and
-resolve a cell to its configuration, traffic and metrics by name."""
+resolve a cell to its configuration, its architecture module, traffic and
+metrics by name."""
 from __future__ import annotations
 
+import importlib.util
 import json
 import pathlib
 import re
@@ -14,6 +16,10 @@ SOURCES_E2E = ("host_clock", "device_trace")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+# what a configuration's architecture module provides (its optional
+# ``init(kind, key, shape)`` draws weight kinds ``weights.make`` lacks)
+ARCH_NAMES = ("layout", "loss", "train_flops_per_token", "matmul_params",
+              "flash_forward_flops", "published_keys")
 
 
 class ManifestError(ValueError):
@@ -121,8 +127,27 @@ def metrics_of(man: dict, cell: str, kind: str) -> list[dict]:
             if cell in m.get("workloads", [cell])]
 
 
+def architecture(name: str, root: pathlib.Path = ROOT):
+    """The architecture module ``bench/reference/<name>.py`` of the
+    checkout at ``root``, loaded from its file: everything of the benchmark
+    that depends on the architecture (``ARCH_NAMES``)."""
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ManifestError(f"reference: bad module name {name!r}")
+    path = root / "bench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise ManifestError(f"reference {name!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_arch_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [k for k in ARCH_NAMES if not hasattr(mod, k)]
+    if missing:
+        raise ManifestError(f"reference {name!r} lacks {missing}")
+    return mod
+
+
 def resolve(man: dict, cell: str, root: pathlib.Path = ROOT) -> dict:
-    """The cell's entry with its configuration and traffic files loaded."""
+    """The cell's entry with its configuration and traffic files loaded,
+    and the architecture module its configuration names (``"arch"``)."""
     try:
         w = next(w for w in man["workloads"] if w["name"] == cell)
     except StopIteration:
@@ -130,10 +155,11 @@ def resolve(man: dict, cell: str, root: pathlib.Path = ROOT) -> dict:
     c = next(c for c in man["configs"] if c["name"] == w["config"])
     with open(root / c["file"]) as f:
         config = json.load(f)
+    arch = architecture(config.get("reference"), root)
     with open(root / "bench" / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     limits_path = root / "bench" / "limits" / f"{cell}.json"
     limits = json.loads(limits_path.read_text()) \
         if limits_path.exists() else None
-    return {"cell": w, "config": config, "traffic": traffic,
+    return {"cell": w, "config": config, "arch": arch, "traffic": traffic,
             "limits": limits}

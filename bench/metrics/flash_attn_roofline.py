@@ -1,7 +1,8 @@
 """Kernels: the flash-attention forward kernel's share of its compute
-roofline: the causal forward's FLOPs from shapes (bench/flops.py) over
-the bf16 peak, per call, over the calls' summed device time."""
-from bench import flops, trace
+roofline: one call's causal forward FLOPs from shapes (the architecture
+module's ``flash_forward_flops``, in the run's ``flash_forward_flops``)
+over the bf16 peak, per call, over the calls' summed device time."""
+from bench import trace
 
 PATTERN = r"^flash_attention(\.\d+)?$"
 
@@ -18,7 +19,5 @@ def read(run):
     spent = sum(e["dur"] for e in calls) / 1e9
     if not calls or spent == 0.0:
         return None
-    t = run["traffic"]
-    rows = t["global_batch"] // t["mesh"][0]
-    f = flops.flash_forward_flops(run["model"], rows, t["seq_len"] - 1)
-    return 100.0 * len(calls) * f / run["peak"]["bf16_flops"] / spent
+    return 100.0 * len(calls) * run["flash_forward_flops"] \
+        / run["peak"]["bf16_flops"] / spent

@@ -1,5 +1,6 @@
-"""Model FLOPs utilisation: forward+backward model FLOPs per token
-(bench/flops.py) times ``tokens_per_s``, over chips times the bf16 peak."""
+"""Model FLOPs utilisation: forward+backward model FLOPs per token (the
+architecture module's ``train_flops_per_token``) times ``tokens_per_s``,
+over chips times the bf16 peak."""
 
 
 def read(run):

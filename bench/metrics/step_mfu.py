@@ -1,8 +1,8 @@
-"""Device: the train step's model FLOPs (bench/flops.py) over its device
-time times the bf16 peak: the whole step's share of the chip's peak.
-The device time is the median duration of the step program in the
-trace's module line (host gaps between steps are left out; ``mfu``
-keeps them)."""
+"""Device: the train step's model FLOPs (the architecture module's
+``train_flops_per_token``) over its device time times the bf16 peak: the
+whole step's share of the chip's peak.  The device time is the median
+duration of the step program in the trace's module line (host gaps
+between steps are left out; ``mfu`` keeps them)."""
 import statistics
 
 from bench import trace

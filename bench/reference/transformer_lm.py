@@ -1,4 +1,8 @@
-"""Plain reference of the decoder-only LM the training cells run.
+"""Architecture module of the pre-norm decoder-only LM (dense or
+sparse-expert layers): the plain reference of its loss, its weight layout,
+its model FLOPs and the published ``config.json`` keys it reads.  A
+configuration file names it with ``"reference": "transformer_lm"``; the
+benchmark's shared code reaches the architecture only through these names.
 
 Straightforward ``jax.numpy``; it imports nothing of the program.  The
 equations are those of a pre-norm Llama-style decoder, as the program's
@@ -37,6 +41,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from bench.flops import attention_pairs
+from bench.weights import padded_vocab
 
 
 FP8 = "float8_e4m3fn"
@@ -183,3 +190,93 @@ def loss(params, tokens, m: dict, compute: str = "float32"):
     lse = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return jnp.mean(lse - gold) + aux
+
+
+# published config.json key -> the program's configuration key
+published_keys = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+                  "intermediate_size": "moe_d_ff",
+                  "num_attention_heads": "n_heads",
+                  "num_key_value_heads": "n_kv_heads",
+                  "num_local_experts": "n_experts",
+                  "num_experts_per_tok": "experts_per_token",
+                  "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+                  "rms_norm_eps": "norm_eps",
+                  "tie_word_embeddings": "tie_embeddings",
+                  "torch_dtype": "param_dtype"}
+
+
+def _hd(m: dict) -> int:
+    return m.get("head_dim") or m["d_model"] // m["n_heads"]
+
+
+def layout(m: dict) -> dict:
+    """Tree of ``(shape, dtype, kind)``, layers stacked on a leading axis;
+    kind is ``embed`` | ``ones`` | ``fan_in`` (``bench/weights.py``); the
+    router in float32."""
+    D, L, V = m["d_model"], m["n_layers"], padded_vocab(m["vocab_size"])
+    hd = _hd(m)
+    H, KV = m["n_heads"], m["n_kv_heads"]
+    dt = m["param_dtype"]
+    block = {
+        "attn_norm": {"w": ((L, D), dt, "ones")},
+        "attn": {"wq": {"w": ((L, D, H * hd), dt, "fan_in")},
+                 "wk": {"w": ((L, D, KV * hd), dt, "fan_in")},
+                 "wv": {"w": ((L, D, KV * hd), dt, "fan_in")},
+                 "wo": {"w": ((L, H * hd, D), dt, "fan_in")}},
+        "mlp_norm": {"w": ((L, D), dt, "ones")},
+    }
+    if m["family"] == "moe":
+        E, F = m["n_experts"], m["moe_d_ff"]
+        block["moe"] = {"router": {"w": ((L, D, E), "float32", "fan_in")},
+                        "wg": ((L, E, D, F), dt, "fan_in"),
+                        "wi": ((L, E, D, F), dt, "fan_in"),
+                        "wo": ((L, E, F, D), dt, "fan_in")}
+    elif m["family"] == "dense":
+        F = m["d_ff"]
+        block["mlp"] = {"wg": ((L, D, F), dt, "fan_in"),
+                        "wi": ((L, D, F), dt, "fan_in"),
+                        "wo": ((L, F, D), dt, "fan_in")}
+    else:
+        raise ValueError(f"no weight layout for family {m['family']!r}")
+    tree = {"embed": {"w": ((V, D), dt, "embed")},
+            "final_norm": {"w": ((D,), dt, "ones")},
+            "blocks": block}
+    if not m.get("tie_embeddings", False):
+        tree["lm_head"] = {"w": ((D, V), dt, "fan_in")}
+    return tree
+
+
+# Model FLOPs per trained token count the matmuls of the published shapes,
+# forward and backward (6 per weight that a token meets): attention's four
+# projections, the MLP's three (for a sparse-expert layer, those of the
+# ``experts_per_token`` experts a token is routed to, plus the router) and
+# the output head over the true vocabulary.  The input-embedding lookup
+# adds none.  Attention's score and value products add ``4 * H * hd`` per
+# attended (query, key) pair forward, 3x that forward and backward; causal
+# masking halves them: a row of S positions attends S(S+1)/2 pairs.
+# Armijo trial forwards and recomputation are not counted.
+
+def matmul_params(m: dict) -> int:
+    """Weights a token is multiplied by, once each."""
+    D, hd = m["d_model"], _hd(m)
+    H, KV = m["n_heads"], m["n_kv_heads"]
+    attn = D * (H + 2 * KV) * hd + H * hd * D
+    if m["family"] == "moe":
+        ffn = m["experts_per_token"] * 3 * D * m["moe_d_ff"] \
+            + D * m["n_experts"]
+    else:
+        ffn = 3 * D * m["d_ff"]
+    return m["n_layers"] * (attn + ffn) + D * m["vocab_size"]
+
+
+def train_flops_per_token(m: dict, seq: int) -> float:
+    """Forward + backward model FLOPs per trained token at ``seq``
+    positions per row."""
+    attn = 3 * 4 * m["n_heads"] * _hd(m) * attention_pairs(seq) / seq
+    return 6.0 * matmul_params(m) + m["n_layers"] * attn
+
+
+def flash_forward_flops(m: dict, rows: int, seq: int) -> float:
+    """One causal attention forward over ``rows`` rows (all layers' calls
+    are alike; this is one call)."""
+    return 4.0 * rows * m["n_heads"] * _hd(m) * attention_pairs(seq)

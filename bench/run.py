@@ -67,11 +67,13 @@ def device_info(devices) -> dict:
 def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
              feed=None, root: pathlib.Path = ROOT, out=sys.stdout,
              compile_cache: bool = True):
-    """One run.  ``require_chip=False``, ``step_wrapper``, ``feed`` and
+    """One run: returns its result, the program's and the reference's
+    records of the checked steps, and the metric readers' context.
+    ``require_chip=False``, ``step_wrapper``, ``feed`` and
     ``compile_cache=False`` are for the tests, which drive a run on the
     CPU with the step broken."""
     import jax
-    from bench import check, flops, harness, manifest, weights
+    from bench import check, harness, manifest, weights
 
     man = manifest.load(root)
     entry = manifest.resolve(man, args.workload, root)
@@ -85,10 +87,10 @@ def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
     devices = devices[:chips]
     if compile_cache:
         harness.env_cache_dir(root)
-    traffic, config = entry["traffic"], entry["config"]
+    traffic, config, arch = entry["traffic"], entry["config"], entry["arch"]
     seed = args.seed
 
-    tr = harness.Trainer(config, traffic, devices=devices)
+    tr = harness.Trainer(config, traffic, arch, devices=devices)
     params, state = tr.fresh_state(seed)
     batch = tr.put(tr.host_batch(seed, 0))
     compile_s = tr.compile(params, state, batch)
@@ -142,7 +144,7 @@ def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
 
     # ---- correctness: the reference's first steps -----------------------
     t_ref = time.perf_counter()
-    ref = harness.reference_record(config, traffic, seed, n_check,
+    ref = harness.reference_record(config, traffic, arch, seed, n_check,
                                    devices=devices)
     nums = check.numbers(prog, ref)
     correct, rows = check.verdict(nums, entry["limits"])
@@ -151,8 +153,8 @@ def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
     # the step's breaker skips every round whose loss or update is not
     # finite: the window's failures are the skips it added
     failed = int(steps[-1]["metrics"]["steps_skipped"] - prog["skipped"])
-    m = config["model"]
-    flop_tok = flops.train_flops_per_token(m, traffic["seq_len"] - 1)
+    m, seq = config["model"], traffic["seq_len"] - 1
+    worker_rows = traffic["global_batch"] // traffic["mesh"][0]
     peaks = json.loads((ROOT / "bench" / "peaks.json").read_text())
     kind = devices[0].device_kind
     if require_chip and kind not in peaks["devices"]:
@@ -162,10 +164,12 @@ def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
     ctx = {
         "model": m, "traffic": traffic,
         "steps": steps, "window_s": window_s, "losses": losses,
-        "tokens_per_step": tokens_per_step, "flops_per_token": flop_tok,
+        "tokens_per_step": tokens_per_step,
+        "flops_per_token": arch.train_flops_per_token(m, seq),
+        "flash_forward_flops": arch.flash_forward_flops(m, worker_rows, seq),
         "chips": len(devices), "peak": peak_row, "setup_s": setup_s,
         "events": events, "opt": harness.optimizer_of(traffic),
-        "shapes": weights.shapes(m),
+        "arch": arch, "shapes": weights.shapes(arch, m),
     }
     kind_key = "per_layer" if traced else "end_to_end"
     metrics = {}
@@ -188,7 +192,7 @@ def run_cell(args, *, require_chip: bool = True, step_wrapper=None,
     for k, v, lim in rows:
         print(f"check {k} {v!r} limit {lim!r}", file=sys.stderr)
     print(json.dumps(result), file=out, flush=True)
-    return result, prog, ref
+    return result, prog, ref, ctx
 
 
 def main(argv=None) -> int:

@@ -192,7 +192,8 @@ def phase_ms(events: list[dict], plane: str, scope_map: dict[str, str],
 
 def step_text(run: dict) -> str | None:
     """The optimized HLO text of the run's step, compiled again from the
-    run's model, traffic and chips; None where the program names no phase.
+    run's model, architecture module, traffic and chips; None where the
+    program names no phase.
 
     The compile finds the run's own executable in the persistent cache.
     That cache's key leaves out ``op_name``, so an executable another
@@ -205,7 +206,7 @@ def step_text(run: dict) -> str | None:
                                          opt_state_shardings)
 
     tr = harness.Trainer({"model": run["model"]}, run["traffic"],
-                         devices=jax.devices()[:run["chips"]])
+                         run["arch"], devices=jax.devices()[:run["chips"]])
 
     def spec(x, sharding):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)

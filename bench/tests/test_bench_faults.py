@@ -25,8 +25,8 @@ def drive(root, cell, **kw):
     args = run.parse(["--workload", cell, "--seed", str(SEED),
                       "--seconds", "1", "--trace", "0"])
     with open(os.devnull, "w") as sink:
-        result, _, _ = run.run_cell(args, require_chip=False, root=root,
-                                    compile_cache=False, out=sink, **kw)
+        result, _, _, _ = run.run_cell(args, require_chip=False, root=root,
+                                       compile_cache=False, out=sink, **kw)
     return result
 
 
@@ -56,9 +56,10 @@ def test_control_is_not_correct(root):
     from bench import manifest
     man = manifest.load(root)
     e = manifest.resolve(man, CELL, root)
-    ref = harness.reference_record(e["config"], e["traffic"], SEED, 3)
-    ctl = harness.reference_record(e["config"], e["traffic"], SEED, 3,
-                                   compute=harness.control_compute(
+    ref = harness.reference_record(e["config"], e["traffic"], e["arch"],
+                                   SEED, 3)
+    ctl = harness.reference_record(e["config"], e["traffic"], e["arch"],
+                                   SEED, 3, compute=harness.control_compute(
                                        e["config"]["model"]))
     ok, rows = check.verdict(check.numbers(ctl, ref), e["limits"])
     assert not ok, rows

@@ -85,24 +85,13 @@ def test_reference_imports_nothing_of_the_program():
             assert not any(n.split(".")[0] == "repro" for n in names), path
 
 
-# published config.json key -> the program's configuration key
-PUBLISHED_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-                  "intermediate_size": "moe_d_ff",
-                  "num_attention_heads": "n_heads",
-                  "num_key_value_heads": "n_kv_heads",
-                  "num_local_experts": "n_experts",
-                  "num_experts_per_tok": "experts_per_token",
-                  "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-                  "rms_norm_eps": "norm_eps",
-                  "tie_word_embeddings": "tie_embeddings",
-                  "torch_dtype": "param_dtype"}
-
-
 def test_configs_depart_from_published_only_in_reduced(man):
+    """Every published key the configuration's architecture module maps
+    (its ``published_keys``) keeps its value unless ``reduced`` names it."""
     for c in man["configs"]:
         f = json.loads((ROOT / c["file"]).read_text())
+        keys = manifest.architecture(f["reference"], ROOT).published_keys
         assert f["reduced"] == c["reduced"], c["name"]
-        changed = {PUBLISHED_KEYS[k] for k, v in f["published"].items()
-                   if k in PUBLISHED_KEYS
-                   and f["model"][PUBLISHED_KEYS[k]] != v}
+        changed = {keys[k] for k, v in f["published"].items()
+                   if k in keys and f["model"][keys[k]] != v}
         assert changed == set(c["reduced"]), (c["name"], changed)

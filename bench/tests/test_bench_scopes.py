@@ -5,7 +5,7 @@ import pathlib
 
 import pytest
 
-from bench import run, scopes, trace
+from bench import manifest, run, scopes, trace
 from bench.tests import tiny
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -21,7 +21,8 @@ def tiny_run():
     t = json.loads((ROOT / "bench" / "traffic" /
                     "csgd-s4096-b2-1x1.json").read_text())
     t.update(seq_len=64, global_batch=4)
-    return {"model": tiny.TINY, "traffic": t, "chips": 1}
+    return {"model": tiny.TINY, "traffic": t, "chips": 1,
+            "arch": manifest.architecture("transformer_lm")}
 
 
 @pytest.fixture(scope="module")
@@ -41,7 +42,7 @@ def test_step_text_is_the_harness_step(tiny_run, step_text):
     of the one the harness compiles and drives."""
     from bench import harness
     tr = harness.Trainer({"name": "tiny", "model": tiny_run["model"]},
-                         tiny_run["traffic"])
+                         tiny_run["traffic"], tiny_run["arch"])
     params, state = tr.fresh_state(0)
     tr.compile(params, state, tr.put(tr.host_batch(0, 0)))
 
@@ -76,7 +77,7 @@ def test_step_text_compiles_past_a_cached_step_without_names(
                       lambda name: contextlib.nullcontext())
             tr = harness.Trainer({"name": "tiny",
                                   "model": tiny_run["model"]},
-                                 tiny_run["traffic"])
+                                 tiny_run["traffic"], tiny_run["arch"])
             params, state = tr.fresh_state(0)
             tr.compile(params, state, tr.put(tr.host_batch(0, 0)))
             cached = tr.step_fn.as_text()

@@ -112,12 +112,15 @@ def test_recorded_trace(recorded):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     m = tiny.lm100m()
+    from bench import manifest
     from bench.weights import shapes
+    lm = manifest.architecture("transformer_lm")
     opt = csgd.Optimizer(gamma=0.01, block=1024, value_bits=32)
     least = (2 * 107520 * 4096 + 107520 * 12
              + 4 * 107520 * 4096 + 107520 * 4) / 819e9
-    share = mod.read({"events": recorded, "opt": opt, "shapes": shapes(m),
+    share = mod.read({"events": recorded, "opt": opt,
+                      "shapes": shapes(lm, m),
                       "peak": {"hbm_bytes_per_s": 819e9}})
     assert share == pytest.approx(100 * least / (6423849.0 / 1e9))
     assert 0 < share < 100
-    assert flops.ef_rows(shapes(m), opt) == 107520
+    assert flops.ef_rows(shapes(lm, m), opt) == 107520

@@ -1,6 +1,7 @@
 """A benchmark checkout at CPU size for the tests: the real manifest's
 metrics, one cell cut from a real cell (its traffic with 64-token rows,
-its limits), and a two-layer model of the same family and precision."""
+its limits), and a two-layer model of the same family and precision,
+with the checkout's own copy of its architecture module."""
 from __future__ import annotations
 
 import dataclasses
@@ -32,10 +33,13 @@ def make_root(tmp: pathlib.Path, cell: str, rows: int = 4,
     its mesh and its limits."""
     man = json.loads((ROOT / "BENCHMARK.json").read_text())
     w = next(w for w in man["workloads"] if w["name"] == cell)
-    for d in ("configs", "traffic", "limits"):
+    for d in ("configs", "traffic", "limits", "reference"):
         (tmp / "bench" / d).mkdir(parents=True, exist_ok=True)
     (tmp / "bench" / "configs" / "tiny.json").write_text(
-        json.dumps({"name": "tiny", "model": TINY}))
+        json.dumps({"name": "tiny", "reference": "transformer_lm",
+                    "model": TINY}))
+    shutil.copy(ROOT / "bench" / "reference" / "transformer_lm.py",
+                tmp / "bench" / "reference" / "transformer_lm.py")
     t = json.loads((ROOT / "bench" / "traffic" /
                     f"{w['traffic']}.json").read_text())
     t.update(seq_len=seq, global_batch=rows * t["mesh"][0], loss_steps=8,
